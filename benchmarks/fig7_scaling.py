@@ -5,8 +5,9 @@ distributed SpMM.  Two parts:
 
   model: per-shard x-traffic (allgather vs on-demand) for 1..64 shards —
          the distributed version of Fig 7's saturation analysis;
-  measured: ring vs allgather SpMM on 8 fake CPU devices (subprocess, so
-         the benchmark process keeps single-device jax).
+  measured: ring vs allgather SpMM on 8 fake CPU devices (a subprocess
+         held to the CPU, so the benchmark process keeps its own device;
+         a failing child fails the run).
 """
 import os
 import subprocess
@@ -41,12 +42,13 @@ def _measure_8dev():
         from repro.core import csr_from_dense
         from repro.core.formats import CSRMatrix
         from repro.core.partition import stack_csr_shards
+        from repro.core.compat import make_mesh
         from repro.core.distributed import allgather_spmm
         from repro.data.suite import generate
         a = generate("cant", scale=1/64)
         n = a.shape[0] - a.shape[0] % 8
         for P_ in (2, 4, 8):
-            mesh = jax.make_mesh((P_,), ("x",))
+            mesh = make_mesh((P_,), ("x",), devices=jax.devices()[:P_])
             bounds = np.linspace(0, n, P_ + 1).astype(int)
             shards = []
             for s in range(P_):
@@ -73,14 +75,18 @@ def _measure_8dev():
             print(f"fig7_measured_cant_p{P_},{t*1e6:.1f},{gf:.2f}GF")
     """)
     env = dict(os.environ)
+    # The child measures 8 fake CPU devices.  It must never reach for the
+    # accelerator: a parent that has touched JAX holds the chip, and a
+    # child that asks for it then fails or hangs.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=600)
-        if out.returncode != 0:
-            return [f"fig7_measured_error,0.0,{out.stderr.splitlines()[-1][:80]}"]
-        return [l for l in out.stdout.splitlines() if l.startswith("fig7")]
-    except Exception as e:  # pragma: no cover
-        return [f"fig7_measured_error,0.0,{type(e).__name__}"]
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"fig7 8-device child failed (rc={out.returncode}):\n"
+            f"{out.stderr[-2000:]}"
+        )
+    return [l for l in out.stdout.splitlines() if l.startswith("fig7")]

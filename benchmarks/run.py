@@ -15,7 +15,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated figure keys")
     args = ap.parse_args()
+    from repro.core.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     from . import (
         fig1_read_bw,
         fig2_write_bw,
@@ -63,6 +65,7 @@ def main() -> None:
     }
     only = set(args.only.split(",")) if args.only else None
     lines: list = ["name,us_per_call,derived"]
+    errors = []
     for key, mod in figures.items():
         if only and key not in only:
             continue
@@ -73,8 +76,11 @@ def main() -> None:
         except Exception as e:
             lines.append(f"{key}_ERROR,0.0,{type(e).__name__}:{e}")
             status = f"ERROR {e}"
+            errors.append(key)
         print(f"# [{key}] {status}", file=sys.stderr, flush=True)
     print("\n".join(lines), flush=True)
+    if errors:  # every figure still ran, but the run did not succeed
+        sys.exit(f"figures failed: {', '.join(errors)}")
 
 
 if __name__ == "__main__":

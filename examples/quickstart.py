@@ -52,7 +52,7 @@ def main():
     sell = kops.sell_prepare(sell_from_csr(a, C=8, sigma=64, width_align=8))
     y_k = kops.sell_spmv(sell, x)
     bcsr = kops.bcsr_prepare(bcsr_from_csr(a, (8, 16)))
-    Y_k = kops.bcsr_spmm(bcsr, X, n_tile=16)
+    Y_k = kops.bcsr_spmm(bcsr, X)
     print(f"  kernels agree: SpMV {np.allclose(y, y_k, atol=1e-3)}, "
           f"SpMM {np.allclose(Y, Y_k, atol=1e-3)}")
 

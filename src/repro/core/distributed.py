@@ -279,10 +279,9 @@ def mesh_spmm_runner(mesh, axis: str, prep: dict[str, Any],
     program, and the padded per-shard row slabs are stitched back into the
     original row order.  Everything past the placement — padding, the
     collective schedule, and the slab stitch (``shard_rows``/``n_pad`` are
-    static host constants) — compiles into ONE jitted program whose only
-    per-call operand is the RHS: the placed shard arrays are closed over as
-    compile-time constants, so a mesh dispatch never re-flattens the operand
-    pytree.
+    static host constants) — compiles into ONE jitted program.  The placed
+    shard arrays enter it as arguments: closed over, they would be written
+    into the program as (replicated) constants.
 
     ``donate_rhs=True`` additionally donates the RHS buffer to the program
     (the serving engine owns its assembled batch slabs outright and never
@@ -296,12 +295,12 @@ def mesh_spmm_runner(mesh, axis: str, prep: dict[str, Any],
     sched = allgather_spmm if prep["schedule"] == "allgather" else ring_spmm
     x_sharding = jax.sharding.NamedSharding(mesh, P(axis))
 
-    @functools.partial(jax.jit, donate_argnums=(0,) if donate_rhs else ())
-    def run(x2):
+    @functools.partial(jax.jit, donate_argnums=(1,) if donate_rhs else ())
+    def run(arrays, x2):
         if x2.shape[0] < n_pad:
             pad = jnp.zeros((n_pad - x2.shape[0], x2.shape[1]), x2.dtype)
             x2 = jnp.concatenate([x2, pad], axis=0)
-        ys = sched(mesh, axis, placed, x2).reshape(P_, -1, x2.shape[1])
+        ys = sched(mesh, axis, arrays, x2).reshape(P_, -1, x2.shape[1])
         return assemble_rows(ys, shard_rows)
 
     # The "donated buffers were not usable" diagnostic can only fire while
@@ -317,10 +316,10 @@ def mesh_spmm_runner(mesh, axis: str, prep: dict[str, Any],
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable"
                 )
-                y = run(x2)
+                y = run(placed, x2)
             warmed_shapes.add(x2.shape)
             return y
-        return run(x2)
+        return run(placed, x2)
 
     def fn(x):
         x2 = x[:, None] if x.ndim == 1 else x

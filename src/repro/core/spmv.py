@@ -69,40 +69,33 @@ def csr_prepare(a) -> dict[str, Any]:
     return dev
 
 
-def csr_bind(dev: dict[str, Any], *, n_rows: int, k: int = 1):
-    """Close a prepared CSR dict over as jit-time constants → ``fn(x)``.
+@functools.partial(jax.jit, static_argnames=("n_rows",))
+def _csr_apply(data, indices, rows, x, *, n_rows: int) -> jax.Array:
+    prod = (data * x[indices] if x.ndim == 1
+            else data[:, None] * x[indices, :])
+    return jax.ops.segment_sum(prod, rows, num_segments=n_rows)
+
+
+def csr_bind(dev: dict[str, Any], *, n_rows: int):
+    """Bind a prepared CSR dict into ``fn(x)``.
 
     The dict-argument entry points above flatten and hash a 4-leaf pytree on
-    every call — measurable against serving-rate dispatch.  Binding the
-    prepared leaves into the jaxpr as constants leaves ``x`` as the only
-    per-call operand, which is what the engine's persistent executables
-    lower.  The trade is per-matrix: the bound arrays are captured by this
-    function's compiled program (one extra resident copy, and compilation is
-    no longer shared across same-shaped matrices) — use it for operators
-    that live across many dispatches, not for one-shot math.
+    every call; binding resolves the three leaves a dispatch reads once, so
+    ``x`` is the only per-call operand.  The leaves reach the compiled
+    program as *arguments* — never as constants closed into it, which
+    would copy the whole matrix into every program that calls ``fn``
+    (see ``runtime.executable.hoisted_jit``, which the engine's and the
+    solver's programs use to lift them).
 
-    ``k=1`` binds the SpMV form (x is ``(n,)``); ``k>1`` binds SpMM
-    (x is ``(n, k)``).
+    The operand's rank picks the form: ``(n,)`` is SpMV, ``(n, k)`` SpMM.
     """
     data, indices = dev["data"], dev["indices"]
     rows = dev["rows"] if "rows" in dev else _rows_from_indptr(
         dev["indptr"], indices.shape[0], n_rows
     )
-    if k == 1:
 
-        @jax.jit
-        def fn(x):
-            return jax.ops.segment_sum(
-                data * x[indices], rows, num_segments=n_rows
-            )
-
-    else:
-
-        @jax.jit
-        def fn(x):
-            return jax.ops.segment_sum(
-                data[:, None] * x[indices, :], rows, num_segments=n_rows
-            )
+    def fn(x):
+        return _csr_apply(data, indices, rows, x, n_rows=n_rows)
 
     return fn
 
@@ -209,7 +202,10 @@ def spmm_bcsr_dense(
     """
     blocks, bcols, brows = bcsr["blocks"], bcsr["block_cols"], bcsr["block_rows"]
     gathered = x_blocked[bcols]  # (n_blocks, bk, k)
-    prods = jnp.einsum("bij,bjk->bik", blocks, gathered)
+    # HIGHEST: on TPU a default-precision f32 einsum runs reduced-precision
+    # MXU passes, and this tier is the float32 reference for the bcsr kernel.
+    prods = jnp.einsum("bij,bjk->bik", blocks, gathered,
+                       precision=jax.lax.Precision.HIGHEST)
     return jax.ops.segment_sum(prods, brows, num_segments=n_block_rows)
 
 

@@ -11,32 +11,36 @@ balance *in the decomposition*: split the nonzero stream — not the rows —
 into equal work chunks, reduce each chunk independently, and fix up the rows
 that straddle chunk boundaries with a carry pass.
 
-This module is that algorithm in its segmented-scan form, which XLA compiles
-to dense, perfectly balanced vector code with NO data-dependent scatter:
+This module is that algorithm as a segmented reduction, which XLA compiles
+to dense, perfectly balanced vector code with NO data-dependent scatter
+over the nonzeros:
 
-* prepare (host, once): pad nnz to ``n_chunks * chunk``; hoist the row
-  boundary pointers (``indptr`` start/end per row) — the chunk table.
-* phase 1 (chunk-local): products ``A.data * x[cols]`` reshaped
-  (n_chunks, chunk); an *intra-chunk* inclusive scan.
-* phase 2 (carry/fixup): an exclusive scan over the per-chunk totals adds
-  each chunk's carry-in, merging partial rows that straddle chunk
-  boundaries into one global prefix-sum table P.
-* gather: row r's sum is ``P[end[r]] - P[start[r]]`` — O(1) per row
-  whatever its length, so a 4700-nonzero webbase row costs exactly what an
-  empty row costs.  Empty rows (start == end) fall out as exact zeros.
+* prepare (host, once): pad nnz to ``n_chunks * chunk`` and lay the
+  streams out slot-major, (chunk, n_chunks), so one step of the scan
+  touches the same slot of every chunk; mark each row's first nonzero
+  with a head flag (the padding tail gets one too, so it never joins a
+  row); hoist each row's last slot and, for the rows that straddle chunk
+  boundaries, which chunk tails belong to them — the chunk table.
+* phase 1 (chunk-local): products ``A.data * x[cols]``, then a segmented
+  running sum down the ``chunk`` slots of all chunks at once, restarting
+  at every head.
+* phase 2 (carry/fixup): the running sum at each chunk's last slot is the
+  partial of the row open there; a segment sum over those n_chunks tails
+  merges each straddling row's partials into one carry.
+* gather: row r's sum is the running sum at its last slot plus its carry
+  — O(1) per row whatever its length, so a 4700-nonzero webbase row costs
+  exactly what an empty row costs.  Empty rows are exact zeros.
 
-Cost is O(nnz) scan + O(m) gathers, independent of the row distribution —
-the tier the tuner reaches for when ``nnz_row_cv`` says SELL padding and
-row-parallel CSR will burn (see tune.candidates' imbalance cost term).
+Cost is O(nnz) adds in ``chunk`` sequential steps + O(m) gathers,
+independent of the row distribution — the tier the tuner reaches for when
+``nnz_row_cv`` says SELL padding and row-parallel CSR will burn (see
+tune.candidates' imbalance cost term).
 
-Precision caveat: a row's sum is a *difference of global prefix sums*, so
-its absolute error scales with eps * |P[end]| — for matrices whose products
-are systematically same-signed, |P| grows ~linearly in nnz and late rows
-with small true sums lose relative precision vs the per-row CSR reduction
-(the chunked scan shortens the sequential carry chain but not the magnitude
-of the prefix).  Zero-mean data (this suite, and most FEM/graph weights) is
-unaffected: |P| stays O(sqrt(nnz)).  For same-signed data at large nnz,
-prefer the CSR/SELL tiers or widen the accumulator dtype upstream.
+Precision: a row's sum adds that row's products and nothing else, so its
+error is bounded by ~nnz_row * eps * (|A| |x|)_i like any float32 row
+reduction.  Rows taken as differences of one global prefix-sum table would
+carry the rounding of the whole prefix instead: at webbase-1M's full size a
+short row late in the stream came back off by half of (|A| |x|)_i.
 """
 from __future__ import annotations
 
@@ -53,15 +57,19 @@ DEFAULT_CHUNK = 4096  # equal-nnz work chunk (the merge-path grain)
 
 
 def merge_prepare(a, chunk: int = DEFAULT_CHUNK) -> dict[str, Any]:
-    """Host-side chunk table: padded nnz streams + hoisted row pointers.
+    """Host-side chunk table: slot-major padded streams, heads, row ends.
 
-    The returned dict is a jit-stable pytree: ``indices``/``data`` are padded
-    to ``n_chunks * chunk`` (padding gathers x[0] with value 0.0 — harmless),
-    ``start``/``end`` are the per-row prefix-sum gather offsets.  ``chunk``
-    and ``n_chunks`` ride along as static python ints.
+    The returned dict is a jit-stable pytree.  ``indices``/``data``/
+    ``heads`` are (chunk, n_chunks): nonzero ``s`` sits at
+    ``[s % chunk, s // chunk]``, and padding gathers x[0] with value 0.0
+    in a segment of its own.  ``last_slot``/``last_chunk`` locate each
+    row's last nonzero (``nonempty`` masks empty rows).  ``tail_row`` names,
+    for each chunk, the straddling row open at its end (``n_straddle`` for
+    none), and ``carry_row`` each row's straddle id (``n_straddle`` for a
+    row held in one chunk).  ``chunk``, ``n_chunks`` and ``n_straddle``
+    ride along as static python ints.
 
-    The gather offsets are int32 (they index the prefix-sum table P, whose
-    length is the padded nnz): a matrix with nnz >= 2**31 cannot be
+    The offsets are int32: a matrix with nnz >= 2**31 cannot be
     represented by this tier and is rejected here — loudly, because the
     ``astype(np.int32)`` below would otherwise WRAP the large indptr tails
     to negative offsets and the kernel would return silently wrong values
@@ -70,51 +78,97 @@ def merge_prepare(a, chunk: int = DEFAULT_CHUNK) -> dict[str, Any]:
     chunk = max(1, int(chunk))
     nnz = a.nnz
     n_chunks = max(1, -(-nnz // chunk))
-    if int(a.indptr[-1]) >= 2**31 or n_chunks * chunk >= 2**31:
+    nnz_pad = n_chunks * chunk
+    if int(a.indptr[-1]) >= 2**31 or nnz_pad >= 2**31:
         raise OverflowError(
-            f"merge tier: nnz={int(a.indptr[-1])} (padded {n_chunks * chunk}) "
-            "overflows the int32 prefix-sum offsets; this matrix needs the "
+            f"merge tier: nnz={int(a.indptr[-1])} (padded {nnz_pad}) "
+            "overflows the int32 slot offsets; this matrix needs the "
             "CSR/SELL tiers (or row-partitioned shards each below 2**31 nnz)"
         )
-    pad = n_chunks * chunk - nnz
+    pad = nnz_pad - nnz
     indices = np.concatenate([a.indices, np.zeros(pad, a.indices.dtype)])
     data = np.concatenate([a.data, np.zeros(pad, a.data.dtype)])
+    start = np.asarray(a.indptr[:-1], np.int64)
+    end = np.asarray(a.indptr[1:], np.int64)
+    nonempty = end > start
+    heads = np.zeros(nnz_pad, np.bool_)
+    heads[start[nonempty]] = True
+    if pad:
+        heads[nnz] = True
+    last = np.where(nonempty, end - 1, 0)
+    c0, c1 = start // chunk, last // chunk
+    straddle = nonempty & (c1 > c0)
+    n_straddle = int(straddle.sum())
+    carry_row = np.full(a.shape[0], n_straddle, np.int64)
+    carry_row[straddle] = np.arange(n_straddle)
+    # Chunks c0 .. c1-1 of a straddling row end inside it: their tails are
+    # its partial sums.
+    span = (c1 - c0)[straddle]
+    tails = np.repeat(c0[straddle], span) + (
+        np.arange(int(span.sum())) - np.repeat(np.cumsum(span) - span, span)
+    )
+    tail_row = np.full(n_chunks, n_straddle, np.int64)
+    tail_row[tails] = np.repeat(np.arange(n_straddle), span)
+
+    def slot_major(v):
+        return jnp.asarray(v.reshape(n_chunks, chunk).T)
+
     return {
-        "indices": jnp.asarray(indices),
-        "data": jnp.asarray(data),
-        "start": jnp.asarray(a.indptr[:-1].astype(np.int32)),
-        "end": jnp.asarray(a.indptr[1:].astype(np.int32)),
+        "indices": slot_major(indices),
+        "data": slot_major(data),
+        "heads": slot_major(heads),
+        "last_slot": jnp.asarray((last % chunk).astype(np.int32)),
+        "last_chunk": jnp.asarray(c1.astype(np.int32)),
+        "nonempty": jnp.asarray(nonempty),
+        "tail_row": jnp.asarray(tail_row.astype(np.int32)),
+        "carry_row": jnp.asarray(carry_row.astype(np.int32)),
         "chunk": chunk,
         "n_chunks": n_chunks,
+        "n_straddle": n_straddle,
         "shape": a.shape,
     }
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "n_chunks"))
-def _prefix_table(data, indices, x2, *, chunk, n_chunks):
-    """P (1 + n_chunks*chunk, k): global prefix sums of A.data * x[cols].
+@functools.partial(jax.jit, static_argnames=("n_straddle",))
+def _merge_apply(data, indices, heads, last_slot, last_chunk, nonempty,
+                 tail_row, carry_row, x2, *, n_straddle):
+    """Y (m, k) = A @ X2 from the slot-major chunk table.
 
-    Phase 1 scans within chunks, phase 2 folds the carry of chunk totals in
-    — the merge of boundary-straddling partial rows.
+    Products are laid out (chunk, k, n_chunks), or (chunk, n_chunks) at
+    k = 1, so each scan step is one lane-dense row of every chunk.
     """
-    prod = data[:, None] * x2[indices, :]  # (nnz_pad, k)
-    k = prod.shape[-1]
-    pc = prod.reshape(n_chunks, chunk, k)
-    local = jnp.cumsum(pc, axis=1)  # intra-chunk scan
-    carry = jnp.concatenate(
-        [jnp.zeros((1, k), prod.dtype), jnp.cumsum(local[:, -1, :], axis=0)[:-1]]
-    )  # exclusive scan of chunk totals: the carry/fixup pass
-    P = (local + carry[:, None, :]).reshape(n_chunks * chunk, k)
-    return jnp.concatenate([jnp.zeros((1, k), prod.dtype), P], axis=0)
+    k = x2.shape[1]
+    if k == 1:
+        prod = data * x2[indices, 0]
+        h = heads
+    else:
+        prod = data[:, None, :] * jnp.moveaxis(x2[indices, :], 2, 1)
+        h = heads[:, None, :]
+
+    def step(v, ph):
+        p, hj = ph
+        v = jnp.where(hj, p, v + p)
+        return v, v
+
+    # Phase 1: every chunk's segmented running sum, one slot per step.
+    _, S = jax.lax.scan(step, jnp.zeros_like(prod[0]), (prod, h), unroll=8)
+    # Phase 2: the partial of the row open at each chunk's end, summed per
+    # straddling row (ids == n_straddle are dropped).
+    tails = S[-1][:, None] if k == 1 else S[-1].T  # (n_chunks, k)
+    carry = jax.ops.segment_sum(tails, tail_row, num_segments=n_straddle)
+    carry = jnp.concatenate([carry, jnp.zeros((1, k), carry.dtype)])
+    y = (S[last_slot, last_chunk][:, None] if k == 1
+         else S[last_slot, :, last_chunk])
+    return jnp.where(nonempty[:, None], y, 0) + carry[carry_row]
 
 
 def merge_spmm(prep: dict[str, Any], x: jax.Array) -> jax.Array:
     """Y = A @ X, X (n, k): nnz-balanced segmented reduction."""
-    P = _prefix_table(
-        prep["data"], prep["indices"], x,
-        chunk=prep["chunk"], n_chunks=prep["n_chunks"],
+    return _merge_apply(
+        prep["data"], prep["indices"], prep["heads"], prep["last_slot"],
+        prep["last_chunk"], prep["nonempty"], prep["tail_row"],
+        prep["carry_row"], x, n_straddle=prep["n_straddle"],
     )
-    return P[prep["end"], :] - P[prep["start"], :]
 
 
 def merge_spmv(prep: dict[str, Any], x: jax.Array) -> jax.Array:

@@ -2,10 +2,18 @@
 
 Handles everything the raw kernels don't: empty-block-row padding, x column
 slabbing (cache blocking) for matrices whose x does not fit in VMEM, output
-un-permutation for SELL, and interpret-mode selection (interpret=True on CPU
-— the kernels' TPU lowering is exercised in the dry-run, their numerics
-here).  The kernels themselves stream their A (and x-slab) operands through
-the shared double-buffered slab pipeline (kernels/pipeline.py).
+un-permutation for SELL, and interpret-mode selection (interpret=True on
+CPU, where the kernels' numerics are tested).  The kernels themselves
+stream their A (and x-slab) operands through the shared double-buffered
+slab pipeline (kernels/pipeline.py).
+
+On TPU every kernel here compiles for v5e at Table-1 sizes
+(``tests/test_tpu_compile.py`` compiles each one for a described v5e
+topology): SELL SpMV resident and column-slab, BCSR SpMM at k = 1 and 16,
+and the SpMSpV scatter.  None is taken out of the TPU search space for a
+compiler refusal; ``tune.candidates.enumerate_candidates`` enumerates each
+only where its resident operands fit :data:`VMEM_BUDGET_BYTES` (and the
+SELL tiles fit SMEM), which is a size rule, not a refusal.
 """
 from __future__ import annotations
 
@@ -18,8 +26,9 @@ import numpy as np
 
 from repro.core.formats import BCSRMatrix, SELLMatrix
 from repro.core.formats import nnz_row_ids as formats_nnz_row_ids
-from .bcsr_spmm import bcsr_spmm_pallas
-from .sell_spmv import sell_spmv_blocked_pallas, sell_spmv_pallas
+from .bcsr_spmm import TILE_ROWS, bcsr_spmm_pallas
+from .pipeline import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
+from .sell_spmv import CHUNK_ALIGN, sell_spmv_blocked_pallas, sell_spmv_pallas
 
 __all__ = [
     "on_cpu",
@@ -32,11 +41,13 @@ __all__ = [
     "sell_spmv_blocked",
     "sell_spmv_blocked_stacked",
     "VMEM_BUDGET_BYTES",
+    "VMEM_LIMIT_BYTES",
 ]
 
-# Conservative per-kernel VMEM working-set budget (v5e has ~128 MiB VMEM; we
-# leave room for double buffering and the output accumulator).
-VMEM_BUDGET_BYTES = 32 * 1024 * 1024
+# Stored blocks per pipeline slab.  The block-index streams ride to SMEM
+# in slabs of this many entries, and Mosaic slices a 1-D HBM array only in
+# whole 1024-entry tiles.
+BLOCK_TILE = 1024
 
 
 def on_cpu() -> bool:
@@ -46,12 +57,16 @@ def on_cpu() -> bool:
 # ---------------------------------------------------------------------------
 # BCSR
 # ---------------------------------------------------------------------------
-def bcsr_prepare(a: BCSRMatrix) -> dict[str, Any]:
-    """Host-side prep: guarantee every block row has >= 1 stored block.
+def bcsr_prepare(a: BCSRMatrix, tile_rows: int = TILE_ROWS) -> dict[str, Any]:
+    """Host-side prep for :func:`bcsr_spmm`.
 
-    Empty block rows get one explicit zero block at column 0 (paper-style
-    fill-in), keeping the kernel's "first visit initializes the tile"
-    invariant true for every output row.
+    * Empty block rows get one explicit zero block at column 0 (paper-style
+      fill-in), so every output row has a stored block.
+    * The row-sorted stream is padded with zero blocks (at the last block
+      row, keeping it sorted) to a multiple of the kernel's slab, so no
+      call re-pads it.
+    * ``window``: the most x rows one ``tile_rows`` row tile's blocks span
+      (8-aligned) — the kernel keeps only that window of X in VMEM.
     """
     gm, _ = a.grid_shape
     present = np.zeros(gm, dtype=bool)
@@ -64,13 +79,31 @@ def bcsr_prepare(a: BCSRMatrix) -> dict[str, Any]:
         [a.blocks, np.zeros((missing.shape[0], bm, bk), a.blocks.dtype)]
     )
     order = np.argsort(block_rows, kind="stable")
+    block_rows, block_cols, blocks = (
+        block_rows[order], block_cols[order], blocks[order]
+    )
+    pad = (-block_rows.shape[0]) % BLOCK_TILE
+    block_rows = np.concatenate(
+        [block_rows, np.full(pad, max(gm - 1, 0), block_rows.dtype)]
+    )
+    block_cols = np.concatenate([block_cols, np.zeros(pad, block_cols.dtype)])
+    blocks = np.concatenate([blocks, np.zeros((pad, bm, bk), blocks.dtype)])
+    tile = block_rows.astype(np.int64) // max(tile_rows // bm, 1)
+    n_tiles = int(tile.max(initial=0)) + 1
+    lo = np.full(n_tiles, np.iinfo(np.int64).max)
+    hi = np.zeros(n_tiles, np.int64)
+    np.minimum.at(lo, tile, block_cols.astype(np.int64))
+    np.maximum.at(hi, tile, block_cols.astype(np.int64) + 1)
+    span = int(((hi - np.minimum(lo, hi)) * bk).max(initial=1))
     return {
-        "block_rows": jnp.asarray(block_rows[order]),
-        "block_cols": jnp.asarray(block_cols[order]),
-        "blocks": jnp.asarray(blocks[order]),
+        "block_rows": jnp.asarray(block_rows),
+        "block_cols": jnp.asarray(block_cols),
+        "blocks": jnp.asarray(blocks),
         "grid_shape": a.grid_shape,
         "block_shape": a.block_shape,
         "shape": a.shape,
+        "window": -(-span // 8) * 8,
+        "tile_rows": int(tile_rows),
     }
 
 
@@ -78,17 +111,13 @@ def bcsr_spmm(
     prep: dict[str, Any],
     x: jax.Array,
     *,
-    n_tile: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Y = A @ X. x: (n, k) unblocked; returns (m, k) unpadded.
 
-    The pipelined kernel keeps a (gm*bm, bn) Y strip and a (gn*bk, bn) X
-    strip VMEM-resident per grid step, so ``bn`` is clamped (by halving,
-    which preserves k-divisibility) until both strips fit the VMEM budget.
-    A matrix so tall that even bn=1 exceeds the budget (> ~4M padded rows)
-    runs over budget rather than failing here — the cost model already
-    prices such shapes out of the pallas tier.
+    The kernel keeps one row tile's Y and the prepared x window of X in
+    VMEM (both lane-padded to 128); ``tune.candidates`` enumerates the
+    kernel only where that window fits the VMEM budget.
     """
     if interpret is None:
         interpret = on_cpu()
@@ -96,14 +125,6 @@ def bcsr_spmm(
     bm, bk = prep["block_shape"]
     m, n = prep["shape"]
     k = x.shape[-1]
-    bn = min(n_tile, k)
-    strip_rows = gm * bm + gn * bk
-    while (
-        bn > 1
-        and strip_rows * bn * x.dtype.itemsize > VMEM_BUDGET_BYTES
-        and k % (bn // 2) == 0
-    ):
-        bn //= 2
     x_pad = jnp.zeros((gn * bk, k), x.dtype).at[:n].set(x)
     out = bcsr_spmm_pallas(
         prep["block_rows"],
@@ -111,7 +132,9 @@ def bcsr_spmm(
         prep["blocks"],
         x_pad.reshape(gn, bk, k),
         n_block_rows=gm,
-        n_tile=bn,
+        window=prep["window"],
+        tile_rows=prep["tile_rows"],
+        block_tile=BLOCK_TILE,
         interpret=interpret,
     )
     return out.reshape(gm * bm, k)[:m]
@@ -121,9 +144,10 @@ def bcsr_spmm(
 # SELL
 # ---------------------------------------------------------------------------
 def sell_prepare(a: SELLMatrix, chunk_tile: int = 8) -> dict[str, Any]:
-    """Host-side prep: pad the chunk count to a multiple of chunk_tile."""
+    """Host-side prep: pad the chunk count to a multiple of CHUNK_ALIGN
+    (itself a multiple of every chunk_tile), the kernel's tile rule."""
     n_chunks = a.n_chunks
-    pad = (-n_chunks) % chunk_tile
+    pad = (-n_chunks) % max(CHUNK_ALIGN, chunk_tile)
     cols, vals, row_perm = a.cols, a.vals, a.row_perm
     if pad:
         cols = np.concatenate([cols, np.zeros((pad,) + cols.shape[1:], cols.dtype)])
@@ -259,7 +283,8 @@ def sell_spmv_blocked(prep: dict[str, Any], x: jax.Array,
 # Stacked column-slab SELL: one launch, x slabs streamed through the pipeline
 # ---------------------------------------------------------------------------
 def sell_prepare_blocked_stacked(a, n_slabs: int, C: int = 8,
-                                 sigma: int = 64) -> dict[str, Any]:
+                                 sigma: int = 64,
+                                 chunk_tile: int = 8) -> dict[str, Any]:
     """Pack A into ``n_slabs`` column slabs sharing ONE row permutation.
 
     Unlike :func:`sell_prepare_blocked` (independent SELL per slab, python
@@ -272,8 +297,8 @@ def sell_prepare_blocked_stacked(a, n_slabs: int, C: int = 8,
 
     Slab widths are uniform (``slab_n = ceil(n / n_slabs)``; x is zero-padded
     to ``n_slabs * slab_n``) so the kernel's x-slab stream is a plain
-    leading-dim slicing — the slab pipeline double-buffers it like any other
-    operand.
+    leading-dim slicing.  The chunk count is padded to a multiple of
+    ``CHUNK_ALIGN``, which the kernel's SMEM tiles divide.
     """
     m, n = a.shape
     slab_n = max(1, -(-n // n_slabs))
@@ -288,6 +313,8 @@ def sell_prepare_blocked_stacked(a, n_slabs: int, C: int = 8,
     inv_perm = np.empty(m, dtype=np.int64)
     inv_perm[perm] = np.arange(m)
     n_chunks = max(1, -(-m // C))
+    align = max(CHUNK_ALIGN, chunk_tile)
+    n_chunks = -(-n_chunks // align) * align
 
     rows_of_nnz = formats_nnz_row_ids(a.indptr, dtype=np.int64)
     slab_of_nnz = a.indices.astype(np.int64) // slab_n
@@ -321,19 +348,21 @@ def sell_prepare_blocked_stacked(a, n_slabs: int, C: int = 8,
         "vals": jnp.asarray(vals),
         "row_perm": jnp.asarray(row_perm),
         "slab_n": slab_n,
+        "chunk_tile": int(chunk_tile),
         "shape": a.shape,
     }
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n_rows", "slab_n", "interpret")
+    jax.jit, static_argnames=("n_rows", "slab_n", "chunk_tile", "interpret")
 )
 def _sell_blocked_stacked_jit(cols, vals, row_perm, x, *, n_rows, slab_n,
-                              interpret):
+                              chunk_tile, interpret):
     n_slabs = cols.shape[0]
     x_pad = jnp.zeros((n_slabs * slab_n,), x.dtype).at[: x.shape[0]].set(x)
     sums = sell_spmv_blocked_pallas(
-        cols, vals, x_pad, slab_n=slab_n, interpret=interpret
+        cols, vals, x_pad, slab_n=slab_n, chunk_tile=chunk_tile,
+        interpret=interpret,
     )
     valid = row_perm >= 0
     y = jnp.zeros((n_rows,), x.dtype)
@@ -351,5 +380,6 @@ def sell_spmv_blocked_stacked(
     m, _ = prep["shape"]
     return _sell_blocked_stacked_jit(
         prep["cols"], prep["vals"], prep["row_perm"], x,
-        n_rows=m, slab_n=int(prep["slab_n"]), interpret=interpret,
+        n_rows=m, slab_n=int(prep["slab_n"]),
+        chunk_tile=prep["chunk_tile"], interpret=interpret,
     )

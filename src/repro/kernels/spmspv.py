@@ -40,7 +40,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.compat import CompilerParams as _CompilerParams
-from .pipeline import resolve_pipelined, slab_pipeline
+from .pipeline import (
+    LANES,
+    SMEM,
+    VMEM_LIMIT_BYTES,
+    add_to_elem,
+    lane_dense,
+    lane_iota,
+    resolve_pipelined,
+    slab_pipeline,
+)
 
 __all__ = [
     "WORK_BUCKET_BASE",
@@ -63,6 +72,7 @@ __all__ = [
 # so the stream always tiles evenly.
 WORK_BUCKET_BASE = 256
 WORK_BUCKET_GROWTH = 4
+SLAB_ALIGN = 1024  # the pallas scatter's slab (and bucket) granularity
 
 
 def validate_sparse_rhs(indices, values, n: int) -> tuple[np.ndarray, np.ndarray]:
@@ -196,16 +206,25 @@ def expand_products(prep: dict, xi, xv, G: int):
     return rows, prods
 
 
+_CSC_KEYS = ("col_start", "col_len", "rows", "vals")
+
+
+def _csc(prep: dict) -> dict:
+    """The device leaves of a prepared CSC view (what a dispatch reads)."""
+    return {k: prep[k] for k in _CSC_KEYS}
+
+
+@functools.partial(jax.jit, static_argnames=("m", "G"))
+def _spmspv_ref(csc, xi, xv, *, m, G):
+    rows, prods = expand_products(csc, xi, xv, G)
+    return jnp.zeros((m,), prods.dtype).at[rows].add(prods)
+
+
 def spmspv_ref_fn(prep: dict, G: int):
     """Jitted reference impl: expansion + one XLA segment scatter."""
     m, _ = prep["shape"]
-
-    @jax.jit
-    def run(xi, xv):
-        rows, prods = expand_products(prep, xi, xv, G)
-        return jnp.zeros((m,), prods.dtype).at[rows].add(prods)
-
-    return run
+    csc = _csc(prep)
+    return lambda xi, xv: _spmspv_ref(csc, xi, xv, m=m, G=G)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "slab", "interpret", "pipelined"))
@@ -214,33 +233,51 @@ def spmspv_scatter_pallas(rows, prods, *, m, slab, interpret=False, pipelined=No
 
     The slab loop is sequential, so read-modify-write accumulation needs no
     atomics; with ``pipelined=True`` the DMA pipeline prefetches slab s+1
-    while slab s scatters.
+    while slab s scatters.  Mosaic lowers no vector scatter
+    (``.at[rows].add`` is refused: "Unimplemented primitive ...
+    scatter-add"), so the (rows, products) slabs stream into SMEM and the
+    scalar core adds each product into the lane-dense accumulator
+    (``pipeline.add_to_elem``: one dynamic row load, masked add, store).
     """
     (G,) = rows.shape
     if G % slab:
         raise ValueError(f"work bucket {G} must tile into slabs of {slab}")
     n_slabs = G // slab
     pipe = resolve_pipelined(pipelined, interpret)
+    out_rows = lane_dense(jnp.zeros((m,), jnp.float32)).shape[0]
 
     def _kernel(rows_hbm, prods_hbm, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
+        lane = lane_iota()
 
         def bucket(s, rows_t, prods_t):
-            o_ref[...] = o_ref[...].at[rows_t].add(prods_t)
+            def eight(ii, _):  # 8 products per trip, unrolled
+                for u in range(8):
+                    i = ii * 8 + u
+                    add_to_elem(o_ref, rows_t[i], prods_t[i], lane)
+                return 0
 
-        slab_pipeline(bucket, [(rows_hbm, slab), (prods_hbm, slab)], n_slabs, pipelined=pipe)
+            jax.lax.fori_loop(0, slab // 8, eight, 0)
 
-    return pl.pallas_call(
+        slab_pipeline(
+            bucket,
+            [(rows_hbm, slab, SMEM), (prods_hbm, slab, SMEM)],
+            n_slabs,
+            pipelined=pipe,
+        )
+
+    out = pl.pallas_call(
         _kernel,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((m,), lambda: (0,)),
-        out_shape=jax.ShapeDtypeStruct((m,), prods.dtype),
-        compiler_params=_CompilerParams(),
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((out_rows, LANES), jnp.float32),
+        compiler_params=_CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(rows, prods)
+    )(rows, prods.astype(jnp.float32))
+    return out.reshape(-1)[:m].astype(prods.dtype)
 
 
 def spmspv_pallas_fn(prep: dict, G: int, slab: int, interpret: bool, pipelined=None):
@@ -248,18 +285,27 @@ def spmspv_pallas_fn(prep: dict, G: int, slab: int, interpret: bool, pipelined=N
     import math
 
     m, _ = prep["shape"]
-    # gcd keeps slab | G for every ladder point (both are power-of-two
-    # multiples of WORK_BUCKET_BASE, so the gcd never drops below the base).
-    slab = max(math.gcd(int(slab), int(G)), 1)
+    # Mosaic slices the 1-D (rows, products) streams only in whole 1024
+    # tiles: the smallest ladder bucket is padded up to one (its extra
+    # lanes are (row 0, value 0)), and gcd keeps slab | G for every ladder
+    # point (both are power-of-two multiples of 1024 from here on).
+    G = max(int(G), SLAB_ALIGN)
+    slab = max(math.gcd(int(slab), G), SLAB_ALIGN)
+    csc = _csc(prep)
+    return lambda xi, xv: _spmspv_pallas(
+        csc, xi, xv, m=m, G=G, slab=slab, interpret=interpret,
+        pipelined=pipelined,
+    )
 
-    @jax.jit
-    def run(xi, xv):
-        rows, prods = expand_products(prep, xi, xv, G)
-        return spmspv_scatter_pallas(
-            rows, prods, m=m, slab=slab, interpret=interpret, pipelined=pipelined
-        )
 
-    return run
+@functools.partial(
+    jax.jit, static_argnames=("m", "G", "slab", "interpret", "pipelined")
+)
+def _spmspv_pallas(csc, xi, xv, *, m, G, slab, interpret, pipelined):
+    rows, prods = expand_products(csc, xi, xv, G)
+    return spmspv_scatter_pallas(
+        rows, prods, m=m, slab=slab, interpret=interpret, pipelined=pipelined
+    )
 
 
 def spmspv_bind(prep: dict, x_nnz: int, *, impl="ref", slab=4096, interpret=None):

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.core import compat
+
 __all__ = ["make_production_mesh", "make_mesh", "make_spmm_mesh", "batch_axes"]
 
 
@@ -16,14 +18,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (2 pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_mesh(pods: int = 1, data: int = 16, model: int = 16):
     """Elastic variant: any (pods, data, model) factorization (launch CLI)."""
     if pods > 1:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return compat.make_mesh((pods, data, model), ("pod", "data", "model"))
+    return compat.make_mesh((data, model), ("data", "model"))
 
 
 def make_spmm_mesh(n_shards: int, *, axis: str = "shard"):
@@ -33,9 +35,6 @@ def make_spmm_mesh(n_shards: int, *, axis: str = "shard"):
     device list, so shard-count sweeps (benchmarks/fig13) can compare
     P in {1, 2, 4, 8} inside one process without re-initializing jax.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
     devices = jax.devices()
     if n_shards > len(devices):
         raise ValueError(
@@ -43,7 +42,7 @@ def make_spmm_mesh(n_shards: int, *, axis: str = "shard"):
             f"visible (set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             f"before jax initializes to fake more on CPU)"
         )
-    return Mesh(np.asarray(devices[:n_shards]), (axis,))
+    return compat.make_mesh((n_shards,), (axis,), devices=devices[:n_shards])
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -168,6 +168,9 @@ def serve_sparse(args) -> None:
         f"  plans={plans}\n"
         f"  ({src}; {raced} candidates pruned by racing)"
     )
+    for k, op in sorted(eng.ops.items()):
+        for cand, err in sorted(op.plan.failed.items()):
+            print(f"  bucket {k}: candidate {cand} failed: {err}")
     if args.stats_json:
         _dump_stats(
             args.stats_json,
@@ -185,6 +188,11 @@ def serve_sparse(args) -> None:
                 "engine": s,
             },
         )
+    faults = eng.supervisor.faults()
+    if faults:
+        # A demoted bucket or a failed batch served (or refused) requests
+        # off the tuned plans: the run is degraded, not a success.
+        raise SystemExit(f"degraded run: supervisor recorded {faults}")
 
 
 def _dump_stats(path: str, payload: dict) -> None:
@@ -382,7 +390,9 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args()
+    from repro.core.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.fleet is not None:
         serve_fleet(args)
         return

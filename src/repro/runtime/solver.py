@@ -10,9 +10,9 @@ way the serving engine did:
 
 * One *solver step* — SpMV/SpMM through the bucket's tuned kernel plus the
   surrounding axpys and dot-product reductions — lowers ONCE per plan into
-  a single on-device program (the prepared-dict leaves are closed over as
-  jit constants via the ``tune.operator.runner`` / ``core.spmv.csr_bind``
-  machinery, exactly like ``runtime.executable``'s bucket programs).
+  a single on-device program (the prepared-dict leaves enter it as
+  device-resident arguments through ``runtime.executable.hoisted_jit``,
+  exactly like the engine's bucket programs).
 * Iterations chain with ``lax.while_loop`` and convergence is checked ON
   DEVICE, so the host sees only the final state: solution, residual norm,
   iteration count, converged flag.  No per-iteration transfer exists to
@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import CSRMatrix
+from repro.runtime.executable import hoisted_jit
 from repro.runtime.faults import FaultPlan, active_plan
 from repro.runtime.supervisor import (
     FALLBACK_TIERS,
@@ -117,8 +118,14 @@ def tridiag_eigvalsh(alphas: np.ndarray, betas: np.ndarray) -> np.ndarray:
 
 
 def _plain_dot(u: jax.Array, v: jax.Array) -> jax.Array:
-    """(n,) x (n,) -> scalar; (n, k) x (n, k) -> (k,) per-column dots."""
-    return jnp.vdot(u, v) if u.ndim == 1 else jnp.sum(u * v, axis=0)
+    """(n,) x (n,) -> scalar; (n, k) x (n, k) -> (k,) per-column dots.
+
+    HIGHEST: on TPU a default-precision f32 dot runs reduced-precision
+    passes, which CG's residual test at tol 1e-5 cannot afford.
+    """
+    if u.ndim == 1:
+        return jnp.vdot(u, v, precision=jax.lax.Precision.HIGHEST)
+    return jnp.sum(u * v, axis=0)
 
 
 class SparseSolver:
@@ -199,7 +206,7 @@ class SparseSolver:
         clear rebinds every program to the fallback operator)."""
         prog = self._progs.get(key)
         if prog is None:
-            prog = self._progs[key] = jax.jit(builder(self.op(k)._run))
+            prog = self._progs[key] = hoisted_jit(builder(self.op(k)._run))
         return prog
 
     def _call(self, key: tuple, k: int, builder: Callable, *args):
@@ -562,11 +569,11 @@ def _host_progs(matvec) -> dict[str, Callable]:
         progs = None
     if progs is None:
         progs = {
-            "cg_setup": jax.jit(
+            "cg_setup": hoisted_jit(
                 lambda b, x, t: _cg_setup(b, x, t, matvec, _plain_dot)
             ),
-            "cg_step": jax.jit(_cg_body(matvec, _plain_dot)),
-            "power_step": jax.jit(_block_power_body(matvec, _plain_dot)),
+            "cg_step": hoisted_jit(_cg_body(matvec, _plain_dot)),
+            "power_step": hoisted_jit(_block_power_body(matvec, _plain_dot)),
         }
         try:
             _HOST_PROGS[matvec] = progs

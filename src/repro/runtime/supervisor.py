@@ -53,6 +53,7 @@ __all__ = [
     "CircuitOpenError",
     "NonFiniteOutput",
     "FALLBACK_TIERS",
+    "FAULT_EVENTS",
     "fallback_op",
 ]
 
@@ -94,6 +95,16 @@ def fallback_op(a, bucket, level: int) -> tuple[str, SparseOperator]:
         b = int(bucket)
         op = SparseOperator.from_candidate(a, cand, k=None if b == 1 else b)
     return name, op
+
+
+# Event kinds that mean a batch or a solve did not run on its tuned plan.
+FAULT_EVENTS = (
+    "batch_failed",
+    "batch_abandoned",
+    "demote",
+    "solver_attempt_failed",
+    "solver_failed",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,6 +164,18 @@ class Supervisor:
     def events_of(self, kind: str) -> list[SupervisorEvent]:
         with self._lock:
             return [e for e in self.events if e.kind == kind]
+
+    def faults(self) -> dict[str, int]:
+        """Counts of the events that mean a run was degraded — a failed or
+        abandoned batch, a demotion, a failed solver attempt — by kind.
+        Empty for a clean run; entry points that must not report success on
+        a degraded run (``chip_smoke.py``, ``launch.serve``) check it."""
+        with self._lock:
+            kinds: dict[str, int] = {}
+            for e in self.events:
+                if e.kind in FAULT_EVENTS:
+                    kinds[e.kind] = kinds.get(e.kind, 0) + 1
+        return kinds
 
     def summary(self) -> dict[str, Any]:
         with self._lock:

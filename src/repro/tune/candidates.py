@@ -51,6 +51,8 @@ __all__ = [
     "RING_STEP_OVERHEAD_BYTES",
     "SOLVER_STEP_AMORTIZE",
     "SOLVER_VEC_PASSES",
+    "SMEM_BUDGET_BYTES",
+    "tpu_pallas_fits",
 ]
 
 SELL_SIGMAS = (1, 64, 256)
@@ -151,6 +153,48 @@ def split_reorder(cand: Candidate) -> tuple[str | None, Candidate]:
     return str(method), make(cand.fmt, cand.impl, **p)
 
 
+# The Pallas kernels' size rules on TPU (see kernels/ops.py).  A kernel is
+# enumerated there only where what it keeps on chip fits: the lane-dense
+# (rows, 128) vectors it indexes element-wise in VMEM, its x window, and
+# the SELL kernel's double-buffered (cols, vals) tiles in SMEM (1 MiB).
+SMEM_BUDGET_BYTES = 512 * 1024
+
+
+def _lane_dense_bytes(n: int) -> int:
+    return -(-max(int(n), 1) // 1024) * 1024 * 4
+
+
+def tpu_pallas_fits(cand: Candidate, feats: MatrixFeatures) -> bool:
+    """Whether a Pallas candidate's on-chip operands fit a v5e core.
+
+    Besides sizes, one compiler refusal shapes the space: Mosaic slices a
+    block stream out of HBM only in whole 128-lane tiles ("Slice shape
+    along dimension 2 must be aligned to tiling (128), but is 8"), so the
+    BCSR kernel runs only with lane-dense (bm, 128) blocks on TPU.
+    """
+    from repro.kernels.bcsr_spmm import TILE_ROWS
+    from repro.kernels.ops import VMEM_BUDGET_BYTES
+    from repro.kernels.sell_spmv import smem_tile_slots
+
+    p = cand.param_dict
+    if cand.fmt in ("sell", "sell_blocked"):
+        smem = 2 * 2 * 4 * smem_tile_slots(
+            int(p.get("chunk_tile", 8)), feats.nnz_row_max
+        )
+        n_slabs = int(p.get("n_slabs", 1))
+        vmem = _lane_dense_bytes(feats.m) + _lane_dense_bytes(
+            -(-feats.n // n_slabs)
+        )
+        return smem <= SMEM_BUDGET_BYTES and vmem <= VMEM_BUDGET_BYTES
+    if cand.fmt == "bcsr":
+        bm, bk = p["block"]
+        window = TILE_ROWS + 2 * int(feats.bandwidth) + 2 * int(bk)
+        return int(bk) == 128 and window * 128 * 4 <= VMEM_BUDGET_BYTES
+    if cand.fmt == "spmspv":
+        return _lane_dense_bytes(feats.m) <= VMEM_BUDGET_BYTES
+    return True
+
+
 def enumerate_candidates(
     feats: MatrixFeatures,
     kind: str = "spmv",
@@ -189,6 +233,9 @@ def enumerate_candidates(
     densification folded into the search.  Square matrices only (RCM is
     defined on the symmetrized pattern); the scalar tier is skipped since
     reordering cannot rescue an unvectorized inner loop.
+
+    On TPU the Pallas candidates are kept only where
+    :func:`tpu_pallas_fits` admits them (see :func:`_fit_backend`).
     """
     if kind == "solver_step":
         kind = "spmv" if int(k) == 1 else "spmm"
@@ -216,7 +263,7 @@ def enumerate_candidates(
         cands.append(make("spmspv", "ref"))
         if include_pallas:
             cands.append(make("spmspv", "pallas", slab=4096))
-        return cands
+        return _fit_backend(cands, feats)
     cands: list[Candidate] = [make("csr", "vector")]
     cands.extend(make("merge", "scan", chunk=int(c)) for c in merge_chunks)
     if kind == "spmv":
@@ -229,12 +276,9 @@ def enumerate_candidates(
                     cands.append(
                         make("sell", "pallas", C=8, sigma=sigma, chunk_tile=ct)
                     )
-    else:
-        # SpMM grew a SELL tier (spmm_sell stacks the RHS through the
-        # chunk-local gathers); the pallas SELL kernel remains k=1-only.
-        for sigma in sigmas:
-            cands.append(make("sell", "ref", C=8, sigma=sigma))
         if not feats.x_fits_vmem:
+            # Column-slab SELL is an SpMV tier: its slabs split x, which at
+            # k > 1 would be an (n, k) slab it has no kernel for.
             from repro.kernels.ops import VMEM_BUDGET_BYTES
 
             n_slabs = max(2, -(-feats.x_bytes // VMEM_BUDGET_BYTES))
@@ -253,6 +297,11 @@ def enumerate_candidates(
                             chunk_tile=8,
                         )
                     )
+    else:
+        # SpMM grew a SELL tier (spmm_sell stacks the RHS through the
+        # chunk-local gathers); the pallas SELL kernel remains k=1-only.
+        for sigma in sigmas:
+            cands.append(make("sell", "ref", C=8, sigma=sigma))
     for block in bcsr_blocks:
         cands.append(make("bcsr", "ref", block=tuple(block)))
         if include_pallas:
@@ -263,7 +312,23 @@ def enumerate_candidates(
             cands.extend(
                 make(c.fmt, c.impl, reorder=method, **c.param_dict) for c in base
             )
-    return cands
+    return _fit_backend(cands, feats)
+
+
+def _fit_backend(cands: list[Candidate], feats: MatrixFeatures) -> list[Candidate]:
+    """On TPU, drop the Pallas candidates :func:`tpu_pallas_fits` refuses,
+    so no kernel reaches the measured search to be refused there.
+    Elsewhere the kernels run in interpret mode and stay enumerated (the
+    cost model prices them out)."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return cands
+    return [
+        c for c in cands
+        if split_reorder(c)[1].impl != "pallas"
+        or tpu_pallas_fits(split_reorder(c)[1], feats)
+    ]
 
 
 def enumerate_mesh_candidates(
@@ -401,10 +466,10 @@ def estimate_cost(
         cv = min(float(feats.nnz_row_cv), ROW_IMBALANCE_CV_CAP)
         bytes_ = bytes_ * (1.0 + ROW_IMBALANCE_WEIGHT * cv)
     elif cand.fmt == "merge":
-        # Equal-nnz chunks: padded product stream in, two-level scan
-        # (read + write ~ one extra pass over the products), two prefix-table
-        # gathers per row.  No term depends on the row distribution — that
-        # is the tier's reason to exist.
+        # Equal-nnz chunks: padded product stream in, segmented scan
+        # (read + write ~ one extra pass over the products), row-end
+        # gathers.  No term depends on the row distribution — that is the
+        # tier's reason to exist.
         chunk = max(1, int(p["chunk"]))
         nnz_pad = max(1, -(-a.nnz // chunk)) * chunk
         bytes_ = (
@@ -432,6 +497,19 @@ def estimate_cost(
             n_blocks * (bm * bk * val_bytes + 2 * idx_bytes)  # fill-in stored
             + (m + n) * k * val_bytes
         )
+        if cand.impl == "ref" and not on_cpu:
+            # On TPU the XLA tier's (n_blocks, bm, bk) blocks, gathered
+            # (bk, k) x tiles and (bm, k) products are each laid out in
+            # (8, 128) tiles: a narrow minor dimension is padded to 128
+            # lanes (webbase-1M's (8, 8) blocks at k = 16 asked for 33 GB
+            # of a v5e's 16 GB HBM).
+            def tiled(rows, cols):
+                return -(-rows // 8) * 8 * -(-cols // 128) * 128 * val_bytes
+
+            bytes_ += n_blocks * (
+                tiled(bm, bk) + tiled(bk, k) + tiled(bm, k)
+                - bm * bk * val_bytes
+            )
     elif cand.fmt == "dist":
         # Collective schedules (core.distributed): per-shard stream bytes
         # plus the traffic needed to make x visible to every shard — the

@@ -44,6 +44,10 @@ class MatrixFeatures:
     # crosses over from dense-RHS tiers as x thins.  Trailing default keeps
     # positional construction of the dense-kind features unchanged.
     x_density: float = 1.0
+    # Longest row: the SELL packing's width W (every chunk pads to it),
+    # which sizes the SELL kernel's SMEM tiles.  Trailing default, like
+    # x_density; not a FEATURE_NAMES axis.
+    nnz_row_max: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         """Plain-python dict, safe for JSON persistence inside a Plan.
@@ -140,4 +144,5 @@ def extract(
         x_bytes=x_bytes,
         x_fits_vmem=x_bytes <= VMEM_BUDGET_BYTES,
         x_density=x_density,
+        nnz_row_max=int(lengths.max(initial=0)),
     )

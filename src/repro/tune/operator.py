@@ -22,6 +22,7 @@ import hashlib
 import math
 import os
 import threading
+import warnings
 from typing import Any, Callable, Iterable
 
 import jax
@@ -47,6 +48,8 @@ from .candidates import (
     enumerate_mesh_candidates,
     split_reorder,
 )
+from repro.runtime.executable import hoisted_jit
+
 from .features import MatrixFeatures, extract
 from .plan import Plan, PlanCache, default_cache, fingerprint
 from .timing import RACE_FACTOR, time_fn
@@ -335,18 +338,21 @@ def solver_step_probe(run, k: int):
     """
     if k == 1:
 
-        @jax.jit
+        @hoisted_jit
         def step(x):
             y = run(x)
             # CG-shaped traffic: two reductions + two axpys over m-vectors.
-            curve = jnp.vdot(x, y)
-            alpha = jnp.vdot(x, x) / jnp.where(curve == 0, 1.0, curve)
+            hi = jax.lax.Precision.HIGHEST
+            curve = jnp.vdot(x, y, precision=hi)
+            alpha = jnp.vdot(x, x, precision=hi) / jnp.where(
+                curve == 0, 1.0, curve
+            )
             r = x - alpha * y
             return r + alpha * x
 
     else:
 
-        @jax.jit
+        @hoisted_jit
         def step(v):
             w = run(v)
             # Block-power-shaped traffic: per-column Rayleigh quotients
@@ -403,17 +409,17 @@ def runner(
             yp = inner(x[perm])
             return jnp.zeros(yp.shape, yp.dtype).at[perm].set(yp)
 
-        return jax.jit(fn)
+        return hoisted_jit(fn)
     if cand.fmt == "csr":
         dev = prep["dev"]
         if cand.impl == "scalar":
             if k > 1:
                 raise ValueError("csr/scalar has no SpMM tier (k > 1)")
             return lambda x: spmv_csr_scalar(dev, x, n_rows=m)
-        # Vector tiers bind the prepared leaves as jit constants: x is the
-        # only per-call operand, so serving-rate dispatch never re-flattens
-        # the 4-leaf dict (see core.spmv.csr_bind for the trade).
-        return csr_bind(dev, n_rows=m, k=k)
+        # Vector tiers bind the prepared leaves once: x is the only
+        # per-call operand, so serving-rate dispatch never re-flattens the
+        # 4-leaf dict (see core.spmv.csr_bind).
+        return csr_bind(dev, n_rows=m)
 
     if cand.fmt == "merge":
         from repro.kernels.merge_spmv import merge_spmm, merge_spmv
@@ -447,15 +453,15 @@ def runner(
                 y = y + spmv_sell(dev, x[bounds[s] : bounds[s + 1]], n_rows=m)
             return y
 
-        return jax.jit(fn)
+        return hoisted_jit(fn)
 
     if cand.fmt == "bcsr":
         gm, gn = prep["grid_shape"]
         bm, bk = prep["block_shape"]
         if cand.impl == "pallas":
             if k == 1:
-                return lambda x: kops.bcsr_spmm(prep, x[:, None], n_tile=1)[:, 0]
-            return lambda x: kops.bcsr_spmm(prep, x, n_tile=min(128, k))
+                return lambda x: kops.bcsr_spmm(prep, x[:, None])[:, 0]
+            return lambda x: kops.bcsr_spmm(prep, x)
         dev = {key: prep[key] for key in ("blocks", "block_cols", "block_rows")}
 
         def fn(x):
@@ -466,7 +472,7 @@ def runner(
             out = out.reshape(gm * bm, kk)[:m]
             return out[:, 0] if x.ndim == 1 else out
 
-        return jax.jit(fn)
+        return hoisted_jit(fn)
 
     raise ValueError(f"unknown candidate format: {cand.fmt}")
 
@@ -497,7 +503,7 @@ def sparse_rhs_runner(
         return spmspv_bind(prep, bucket, impl=cand.impl, **cand.param_dict)
     base = runner(a, cand, prep, k=1)
 
-    @jax.jit
+    @hoisted_jit
     def densified(xi, xv):
         x = jnp.zeros((n,), xv.dtype).at[xi].add(xv, mode="drop")
         return base(x)
@@ -713,8 +719,7 @@ class SparseOperator:
         # same discipline, or with warmup=0 its lone timed rep would eat
         # the compile and bias the search against the cheapest estimate.
         warmup_eff = max(warmup, 1) if race else warmup
-        n_failed = 0
-        last_err: str | None = None
+        failed: dict[str, str] = {}
         for c in survivors:
             try:
                 prep = prepare_cached(a, c, fp=fp, mesh=mesh, axis=axis,
@@ -731,11 +736,18 @@ class SparseOperator:
                             abort_above=abort)
             except Exception as exc:
                 # One candidate failing to prepare or run (OOM under memory
-                # pressure, a broken kernel path) must not kill the whole
-                # search — the others still compete.  inf marks it losing.
+                # pressure, a compile refusal, a broken kernel path) must not
+                # kill the whole search — the others still compete — but it
+                # must not vanish either: it is recorded on the plan
+                # (``Plan.failed``) and warned about.
                 measurements[c.key()] = float("inf")
-                n_failed += 1
-                last_err = f"{c.key()}: {exc!r}"
+                failed[c.key()] = f"{type(exc).__name__}: {exc}"[:1000]
+                warnings.warn(
+                    f"tuner: candidate {c.key()} failed for kind={kind} "
+                    f"k={kk}: {failed[c.key()][:300]}",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
                 continue
             measurements[c.key()] = t
             if math.isinf(t):
@@ -744,11 +756,12 @@ class SparseOperator:
             if best is None or t < best[0]:
                 best = (t, c, prep)
         if best is None:
+            last = next(reversed(failed.items()), None)
             raise RuntimeError(
                 f"measured search found no usable candidate for kind="
                 f"{kind!r} k={kk} ({len(survivors)} survivors, "
-                f"{n_failed} failed"
-                + (f"; last error {last_err}" if last_err else "")
+                f"{len(failed)} failed"
+                + (f"; last error {last[0]}: {last[1]}" if last else "")
                 + ")"
             )
         t_best, c_best, prep_best = best
@@ -773,6 +786,7 @@ class SparseOperator:
             # (tune.predict nearest-neighbors over them for new
             # fingerprints).
             features=feats.to_dict(),
+            failed=failed,
         )
         cache.put(plan)
         return cls(

@@ -115,6 +115,12 @@ class Plan:
     # fallback.  Predicted plans are never persisted — only measured search
     # results enter the cache — so on cached entries this is always "".
     predicted_from: str = ""
+    # Candidates the measured search lost to an exception (compile refusal,
+    # OOM, a broken kernel path): candidate key -> error text.  A search
+    # that silently dropped a tier would report a winner that only won by
+    # default; recording the loss lets callers see it and refuse it.
+    # Schema-additive (empty in older entries), never used for matching.
+    failed: dict = dataclasses.field(default_factory=dict)
     version: int = PLAN_VERSION
 
     def matches(

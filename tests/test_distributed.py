@@ -30,7 +30,8 @@ def test_ring_and_allgather_spmm_match_dense():
         from repro.core.formats import CSRMatrix
         from repro.core.partition import grid_2d, stack_csr_shards
         from repro.core.distributed import allgather_spmm, ring_spmm
-        mesh = jax.make_mesh((4,), ("x",))
+        from repro.core.compat import make_mesh
+        mesh = make_mesh((4,), ("x",))
         rng = np.random.default_rng(2)
         n, k = 64, 8
         d = ((rng.random((n, n)) < 0.1) * rng.standard_normal((n, n))).astype(np.float32)
@@ -70,9 +71,9 @@ def test_ef_compressed_psum_reduces_and_feeds_back_error():
     print(run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp, functools
         from jax.sharding import PartitionSpec as P
-        from repro.core.compat import shard_map
+        from repro.core.compat import make_mesh, shard_map
         from repro.optim.compress import ef_compressed_psum
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         @functools.partial(shard_map, mesh=mesh, in_specs=(P("d"), P("d")),
                            out_specs=(P("d"), P("d")))
         def allred(g, e):
@@ -102,7 +103,8 @@ def test_sharded_train_step_on_2x4_mesh():
         from repro.optim.adamw import OptimConfig, adamw_init
         from repro.runtime.trainer import make_train_step, shardings_for
         from repro.launch.shardspecs import param_shardings, batch_shardings
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.core.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = default_rules(False)
         set_active_rules(rules)
         cfg = ModelConfig(arch_id="t", family="dense", n_layers=2, d_model=64,

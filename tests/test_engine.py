@@ -431,3 +431,19 @@ def test_result_on_aborted_engine_raises_immediately():
     assert time.perf_counter() - t0 < 1.0  # immediate, not timeout-bound
     with pytest.raises(RuntimeError, match="closed"):
         eng.submit(x)
+
+
+def test_bucket_program_takes_prepared_arrays_as_arguments():
+    """The fused bucket program must not carry the matrix as HLO constants:
+    closed over by a plain jit, every prepared array would be copied into
+    the program text (and into every compile) — at Table-1 sizes hundreds
+    of MB per bucket.  The arrays enter as arguments instead."""
+    d, a = small(m=512, density=0.2)
+    eng = engine(a, ks=(4,))
+    prog = eng._exec(4)
+    zero = jnp.zeros((a.shape[1],), jnp.float32)
+    text = prog.lower(*[zero] * 4).as_text()
+    assert a.nnz * 4 > 150_000  # the data stream alone would show up
+    assert len(text) < a.nnz * 4 // 2
+    ys = np.asarray(prog(*[zero] * 4))
+    assert ys.shape == (a.shape[0], 4) and not ys.any()

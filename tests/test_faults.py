@@ -399,3 +399,42 @@ def test_solver_dispatch_fault_retried_then_demoted():
     with pytest.raises(InjectedFault):
         s3.cg(b, tol=1e-6)
     assert s3.supervisor.demotions == 2 and s3.supervisor.failures == 1
+
+
+def test_supervisor_faults_names_degraded_runs():
+    """Supervisor.faults() is what entry points check before reporting
+    success: empty on a clean run, non-empty once a batch failed."""
+    d, a = small(seed=7)
+    eng = engine(a, ks=(1,))
+    eng.run(xs_for(a, 2))
+    assert eng.supervisor.faults() == {}
+    eng.close()
+    plan = FaultPlan({"engine.dispatch": {"n": 1}})
+    faulty = engine(a, ks=(1,), faults=plan,
+                    supervisor=Supervisor(max_retries=1, **SUP_KW))
+    faulty.run(xs_for(a, 2))
+    assert faulty.supervisor.faults() == {"batch_failed": 1}
+    faulty.close()
+
+
+def test_serve_cli_exits_nonzero_on_a_degraded_run(monkeypatch, tmp_path):
+    """`launch.serve --sparse` must not report success when a batch failed
+    (here: one injected dispatch fault, recovered by a retry)."""
+    import sys
+
+    from repro.core.compile_cache import CACHE_ENV
+    from repro.launch import serve
+    from repro.tune import plan as plan_mod
+
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))  # leave jax's cache alone
+    monkeypatch.setattr(plan_mod, "_default", PlanCache())
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--sparse", "cant", "--scale", "0.005", "--requests", "8",
+        "--k-buckets", "1,4",
+    ])
+    prev = set_active(FaultPlan({"engine.dispatch": {"n": 1}}))
+    try:
+        with pytest.raises(SystemExit, match="degraded run"):
+            serve.main()
+    finally:
+        set_active(prev)

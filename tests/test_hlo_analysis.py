@@ -65,7 +65,8 @@ def test_collective_bytes_under_spmd():
         sys.path.insert(0, {src!r})
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.core.compat import make_mesh
+        mesh = make_mesh((8,), ("model",))
         w_sh = NamedSharding(mesh, P(None, "model"))
         x_sh = NamedSharding(mesh, P(None, None))
         def f(x, w):

@@ -81,10 +81,13 @@ def test_bcsr_prepare_all_empty_block_rows():
     b = bcsr_from_csr(a, (8, 8))
     assert b.n_blocks == 0
     prep = kops.bcsr_prepare(b)
-    # Every block row got one explicit zero fill-in block.
-    assert prep["blocks"].shape[0] == 2
+    # Every block row got one explicit zero fill-in block, and the stream
+    # is padded (with zero blocks) to whole kernel slabs.
+    assert set(np.asarray(prep["block_rows"]).tolist()) == {0, 1}
+    assert prep["blocks"].shape[0] == kops.BLOCK_TILE
+    assert not np.asarray(prep["blocks"]).any()
     X = np.random.default_rng(1).standard_normal((16, 4)).astype(np.float32)
-    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X), n_tile=4))
+    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X)))
     np.testing.assert_allclose(out, np.zeros((16, 4)))
 
 
@@ -101,7 +104,7 @@ def test_bcsr_prepare_some_empty_block_rows_vs_dense():
     assert len(np.unique(b.block_rows)) < gm  # some block rows are empty
     prep = kops.bcsr_prepare(b)
     X = rng.standard_normal((24, 8)).astype(np.float32)
-    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X), n_tile=8))
+    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X)))
     np.testing.assert_allclose(out, d @ X, atol=1e-4)
 
 
@@ -190,6 +193,31 @@ def test_merge_empty_matrix_and_oversized_chunk():
     )
 
 
+@pytest.mark.parametrize("chunk,k", [(64, 1), (64, 4), (2048, 1), (2048, 4)])
+def test_merge_rows_are_accurate_elementwise_on_powerlaw(chunk, k):
+    """Each row's error stays within float32 rounding of (|A| |x|)_i.
+
+    Regression: rows were differences of one global prefix-sum table, so a
+    short row late in the stream inherited the rounding of the whole
+    prefix (off by 1e-2 of (|A| |x|)_i here, and by half of it at
+    webbase-1M's full size).  chunk=64 makes the long rows straddle many
+    chunks, exercising the carry."""
+    import scipy.sparse as sp
+
+    from repro.data.suite import generate
+
+    a = generate("webbase-1M", scale=0.01, seed=0)
+    a64 = sp.csr_matrix(
+        (a.data.astype(np.float64), a.indices, a.indptr), shape=a.shape
+    )
+    X = np.random.default_rng(3).standard_normal((a.shape[1], k))
+    X = X.astype(np.float32)
+    got = np.asarray(merge_spmm(merge_prepare(a, chunk), jnp.asarray(X)))
+    ref = a64 @ X.astype(np.float64)
+    bound = abs(a64) @ np.abs(X.astype(np.float64))
+    assert np.all(np.abs(got - ref) <= 1e-5 * bound + 1e-30)
+
+
 # ---------------------------------------------------------------------------
 # Pathological row distributions x every enumerated candidate
 # ---------------------------------------------------------------------------
@@ -264,7 +292,7 @@ def test_merge_prepare_rejects_int32_overflowing_nnz():
     with pytest.raises(OverflowError, match="merge tier"):
         merge_prepare(big, 4096)
     # nnz just under the limit, but chunk padding crosses it: still rejected
-    # (the prefix table is padded-nnz long).
+    # (the slot offsets span the padded nnz).
     near = types.SimpleNamespace(
         nnz=2**31 - 1,
         indptr=np.array([0, 2**31 - 1], np.int64),

@@ -36,7 +36,7 @@ def test_bcsr_spmm_vs_oracle(m, n, k, block, density):
     b = bcsr_from_csr(a, block)
     prep = kops.bcsr_prepare(b)
     X = rng.standard_normal((n, k)).astype(np.float32)
-    out = kops.bcsr_spmm(prep, jnp.asarray(X), n_tile=min(128, k))
+    out = kops.bcsr_spmm(prep, jnp.asarray(X))
     # oracle 1: dense matmul
     np.testing.assert_allclose(np.asarray(out), d @ X, atol=5e-4, rtol=1e-4)
     # oracle 2: ref.py block loop
@@ -86,7 +86,7 @@ def test_bcsr_empty_rows_padded():
     b = bcsr_from_csr(a, (8, 8))
     prep = kops.bcsr_prepare(b)
     X = np.ones((32, 8), np.float32)
-    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X), n_tile=8))
+    out = np.asarray(kops.bcsr_spmm(prep, jnp.asarray(X)))
     np.testing.assert_allclose(out, d @ X, atol=1e-6)
 
 
@@ -97,7 +97,7 @@ def test_bcsr_bf16_inputs():
     prep = kops.bcsr_prepare(b)
     prep["blocks"] = prep["blocks"].astype(jnp.bfloat16)
     X = rng.standard_normal((64, 16)).astype(np.float32)
-    out = kops.bcsr_spmm(prep, jnp.asarray(X).astype(jnp.bfloat16), n_tile=16)
+    out = kops.bcsr_spmm(prep, jnp.asarray(X).astype(jnp.bfloat16))
     np.testing.assert_allclose(np.asarray(out, np.float32), d @ X, atol=0.5, rtol=0.1)
 
 
@@ -159,7 +159,7 @@ def test_slab_pipeline_dma_path_equals_direct_loads():
     outs = [
         np.asarray(bcsr_spmm_pallas(
             prep["block_rows"], prep["block_cols"], prep["blocks"],
-            X.reshape(gn, bk, 16), n_block_rows=gm, n_tile=16,
+            X.reshape(gn, bk, 16), n_block_rows=gm,
             interpret=True, pipelined=p))
         for p in (False, True)
     ]

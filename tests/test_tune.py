@@ -739,3 +739,98 @@ def test_prepare_cached_respects_global_budget_counters():
     assert after["hits"] >= before["hits"] + 1
     assert after["misses"] >= before["misses"]
     assert after["resident_bytes"] >= 0 and after["budget_bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
+# Failures are recorded, never swallowed; the TPU space follows size rules
+# ---------------------------------------------------------------------------
+def test_search_records_failed_candidate_on_plan(tmp_path):
+    """A candidate that raises in the measured search loses, but is warned
+    about and recorded on the plan (and in the persisted cache entry) with
+    its error, so a run can refuse a winner that only won by default."""
+    from repro.tune import make
+
+    _, a = small_csr(seed=5)
+    broken = make("sell_blocked", "ref", C=8, sigma=64, n_slabs=2)  # SpMV-only
+    cache = PlanCache(tmp_path / "plans.json")
+    with pytest.warns(RuntimeWarning, match="sell_blocked/ref"):
+        op = SparseOperator.build(
+            a, k=8, cache=cache, warmup=0, timed=1,
+            candidates=[make("csr", "vector"), broken],
+        )
+    assert op.plan.candidate.key() == "csr/vector"
+    assert set(op.plan.failed) == {broken.key()}
+    assert op.measurements[broken.key()] == float("inf")
+    reloaded = PlanCache(tmp_path / "plans.json").get(
+        op.plan.fingerprint, "spmm", 8
+    )
+    assert reloaded.failed == op.plan.failed
+
+
+def test_tpu_enumeration_applies_kernel_size_rules(monkeypatch):
+    """On TPU, Pallas candidates are enumerated only where their on-chip
+    operands fit (and BCSR only with lane-dense (8, 128) blocks); the
+    column-slab SELL tier is SpMV-only on every backend."""
+    import dataclasses
+
+    import jax
+
+    from repro.tune.candidates import tpu_pallas_fits
+
+    _, a = small_csr(seed=6)
+    feats = extract(a)
+    sparse_feats = extract(a, x_nnz=8)
+    cpu = enumerate_candidates(feats)
+    # From here on the enumeration sees a TPU backend.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tpu = enumerate_candidates(feats)
+    assert [c for c in cpu if c.impl != "pallas"] == [
+        c for c in tpu if c.impl != "pallas"
+    ]
+    tpu_pallas = {c.key() for c in tpu if c.impl == "pallas"}
+    assert "bcsr/pallas[block=(8, 128)]" in tpu_pallas
+    assert not any(k.startswith("bcsr/pallas[block=(8, 8)") for k in tpu_pallas)
+    assert any(k.startswith("sell/pallas") for k in tpu_pallas)
+    assert all(tpu_pallas_fits(c, feats) for c in tpu if c.impl == "pallas")
+    sparse = enumerate_candidates(sparse_feats, "spmspv")
+    assert any(c.key().startswith("spmspv/pallas") for c in sparse)
+
+    # A 10M-row operand: its lane-dense vectors exceed the VMEM budget, so
+    # no SELL kernel is enumerated (BCSR keeps only a band-sized x window,
+    # until the band is as wide as the matrix), and a 5000-wide SELL tile
+    # would not fit SMEM either.
+    huge = dataclasses.replace(feats, m=10_000_000, n=10_000_000,
+                               x_bytes=40_000_000, x_fits_vmem=False)
+    big = enumerate_candidates(huge)
+    assert {c.fmt for c in big if c.impl == "pallas"} == {"bcsr"}
+    assert any(c.fmt == "sell_blocked" for c in big)
+    banded = dataclasses.replace(huge, bandwidth=10_000_000)
+    assert not [c for c in enumerate_candidates(banded)
+                if c.impl == "pallas"]
+    wide = dataclasses.replace(feats, nnz_row_max=5000)
+    assert not any(c.fmt == "sell" and c.impl == "pallas"
+                   for c in enumerate_candidates(wide))
+    spmm = enumerate_candidates(huge, "spmm", k=16)
+    assert not any(c.fmt == "sell_blocked" for c in spmm)
+
+
+def test_tpu_cost_model_prices_lane_padding_of_narrow_xla_bcsr():
+    """On TPU the XLA bcsr tier's (8, 8) blocks and (8, k) tiles are laid
+    out in (8, 128) tiles; the byte model charges that padding there (it
+    asked for 33 GB of a 16 GB chip on webbase-1M at k = 16), so the narrow
+    blocks are pruned against merge/scan, while the CPU estimate is
+    unchanged."""
+    from repro.tune import make
+    from repro.tune.candidates import estimate_cost, prune
+
+    a = generate("webbase-1M", scale=0.01, seed=0)
+    feats = extract(a)
+    narrow = make("bcsr", "ref", block=(8, 8))
+    merge = make("merge", "scan", chunk=2048)
+    cpu = {c: estimate_cost(a, c, feats, k=16, on_cpu=True)
+           for c in (narrow, merge)}
+    tpu = {c: estimate_cost(a, c, feats, k=16, on_cpu=False)
+           for c in (narrow, merge)}
+    assert tpu[merge] == cpu[merge]
+    assert tpu[narrow] > 10 * tpu[merge] and tpu[narrow] > cpu[narrow]
+    assert prune(tpu) == [merge]
