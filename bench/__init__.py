@@ -1,0 +1,7 @@
+"""The on-chip benchmark: one harness, cells declared in ``BENCHMARK.json``.
+
+``bench/run.py`` runs one cell once.  ``bench/lib`` is the yardstick (the
+matrices, the traffic generator, the reference and its limits, the peaks,
+the floor bytes and the trace reduction); ``configs``, ``traffic`` and
+``metrics`` hold one file per configuration, mix and per-layer reader.
+"""
