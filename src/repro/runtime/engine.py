@@ -97,6 +97,7 @@ from typing import Any, Iterable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.distributed import assemble_rows, stacked_spmm
 from repro.core.formats import CSRMatrix
@@ -199,17 +200,18 @@ class EngineRequest:
         ``timeout`` seconds — so a caller can bound its wait even when the
         serving loop itself is wedged.
         """
-        if not self.done:
-            if self._engine is None:
-                raise RuntimeError("request is not attached to an engine")
-            deadline = (
-                None if timeout is None
-                else time.perf_counter() + float(timeout)
-            )
-            self._engine._fulfill(self, deadline=deadline)
-        if self._exc is not None:
-            raise self._exc
-        return self.y
+        with TraceAnnotation("engine.result", rid=self.rid):
+            if not self.done:
+                if self._engine is None:
+                    raise RuntimeError("request is not attached to an engine")
+                deadline = (
+                    None if timeout is None
+                    else time.perf_counter() + float(timeout)
+                )
+                self._engine._fulfill(self, deadline=deadline)
+            if self._exc is not None:
+                raise self._exc
+            return self.y
 
     @property
     def latency_s(self) -> float:
@@ -472,8 +474,10 @@ class SparseEngine:
         self._sparse_ops: dict[int, SparseOperator] = {}
         self._sparse_execs: dict[int, Any] = {}
         self._queue: deque[EngineRequest] = deque()
-        self._inflight: deque[tuple] = deque()  # (ys, reqs, bucket, take)
+        # (ys, ok, reqs, bucket, take, batch)
+        self._inflight: deque[tuple] = deque()
         self._rid = 0
+        self._batch = 0  # sequence number of the next launched batch
         # Blocked callers (result(timeout=), block-policy submits) sleep on
         # this condition and are notified at every retirement/failure
         # instead of burning a poll loop; _serve_lock elects ONE of them to
@@ -536,40 +540,43 @@ class SparseEngine:
         warning once per engine, or raising ``TypeError`` under
         ``strict_dtype=True``.  See the class docstring's dtype policy.
         """
-        self._check_open()
-        if not isinstance(x, jax.Array):  # asarray on a device array costs
-            # Through numpy, NOT jnp: with x64 disabled jnp.asarray folds
-            # float64 to f32 before the dtype is ever observable, which is
-            # exactly the silent downcast this policy exists to surface.
-            x = np.asarray(x)
-        if x.shape != (self.shape[1],):
-            raise ValueError(f"expected x of shape ({self.shape[1]},), got {x.shape}")
-        if x.dtype != jnp.float32:
-            if self.strict_dtype:
-                raise TypeError(
-                    f"submit() got dtype {x.dtype}; this engine serves "
-                    "float32 and strict_dtype=True forbids the implicit cast"
+        with TraceAnnotation("engine.submit", rid=self._rid):
+            self._check_open()
+            if not isinstance(x, jax.Array):  # asarray on a device array costs
+                # Through numpy, NOT jnp: with x64 disabled jnp.asarray folds
+                # float64 to f32 before the dtype is ever observable, which is
+                # exactly the silent downcast this policy exists to surface.
+                x = np.asarray(x)
+            if x.shape != (self.shape[1],):
+                raise ValueError(
+                    f"expected x of shape ({self.shape[1]},), got {x.shape}"
                 )
-            if not self._dtype_warned:
-                self._dtype_warned = True
-                warnings.warn(
-                    f"SparseEngine.submit: casting {x.dtype} input to "
-                    "float32 (the engine's serving dtype) — submit float32 "
-                    "to avoid the cast, or build the engine with "
-                    "strict_dtype=True to make this an error; warning once "
-                    "per engine",
-                    stacklevel=2,
-                )
-            x = jnp.asarray(x, jnp.float32)
-        elif not isinstance(x, jax.Array):
-            x = jnp.asarray(x)
-        self._admit_one()
-        req = EngineRequest(rid=self._rid, x=x, t_submit=time.perf_counter(),
-                            _engine=self)
-        self._rid += 1
-        self._queue.append(req)
-        self.stats.n_requests += 1
-        return req
+            if x.dtype != jnp.float32:
+                if self.strict_dtype:
+                    raise TypeError(
+                        f"submit() got dtype {x.dtype}; this engine serves "
+                        "float32 and strict_dtype=True forbids the implicit cast"
+                    )
+                if not self._dtype_warned:
+                    self._dtype_warned = True
+                    warnings.warn(
+                        f"SparseEngine.submit: casting {x.dtype} input to "
+                        "float32 (the engine's serving dtype) — submit float32 "
+                        "to avoid the cast, or build the engine with "
+                        "strict_dtype=True to make this an error; warning once "
+                        "per engine",
+                        stacklevel=2,
+                    )
+                x = jnp.asarray(x, jnp.float32)
+            elif not isinstance(x, jax.Array):
+                x = jnp.asarray(x)
+            self._admit_one()
+            req = EngineRequest(rid=self._rid, x=x, t_submit=time.perf_counter(),
+                                _engine=self)
+            self._rid += 1
+            self._queue.append(req)
+            self.stats.n_requests += 1
+            return req
 
     # -- bounded admission (runtime.overload) -------------------------------
     def _admit_one(self) -> None:
@@ -661,70 +668,74 @@ class SparseEngine:
         the async in-flight window and retire through the same machinery;
         the returned future behaves exactly like a dense one.
         """
-        self._check_open()
-        b = self._brownout
-        if b is not None and b.state == SHED:
-            # Sparse requests dispatch immediately (no queue to bound), but
-            # SHED refuses them the same way: new work is new load.
-            self.stats.rejected += 1
-            raise OverloadError(
-                f"engine {self.name or 'unnamed'} is shedding load "
-                f"(brownout state={b.state}); resubmit after recovery"
-            )
-        if self.mesh is not None or self.n_shards > 1:
-            raise NotImplementedError(
-                "submit_sparse is single-device for now: distributed SpMSpV "
-                "under the mesh schedules is the ROADMAP follow-on of this "
-                "tier"
-            )
-        from repro.kernels.spmspv import validate_sparse_rhs
+        with TraceAnnotation("engine.submit", rid=self._rid):
+            self._check_open()
+            b = self._brownout
+            if b is not None and b.state == SHED:
+                # Sparse requests dispatch immediately (no queue to bound), but
+                # SHED refuses them the same way: new work is new load.
+                self.stats.rejected += 1
+                raise OverloadError(
+                    f"engine {self.name or 'unnamed'} is shedding load "
+                    f"(brownout state={b.state}); resubmit after recovery"
+                )
+            if self.mesh is not None or self.n_shards > 1:
+                raise NotImplementedError(
+                    "submit_sparse is single-device for now: distributed SpMSpV "
+                    "under the mesh schedules is the ROADMAP follow-on of this "
+                    "tier"
+                )
+            from repro.kernels.spmspv import validate_sparse_rhs
 
-        n = self.shape[1]
-        idx, val = validate_sparse_rhs(indices, values, n)
-        val = np.asarray(val)
-        if val.dtype != np.float32:
-            if self.strict_dtype:
-                raise TypeError(
-                    f"submit_sparse() got values dtype {val.dtype}; this "
-                    "engine serves float32 and strict_dtype=True forbids "
-                    "the implicit cast"
-                )
-            if not self._dtype_warned:
-                self._dtype_warned = True
-                warnings.warn(
-                    f"SparseEngine.submit_sparse: casting {val.dtype} values "
-                    "to float32 (the engine's serving dtype) — submit "
-                    "float32 to avoid the cast, or build the engine with "
-                    "strict_dtype=True to make this an error; warning once "
-                    "per engine",
-                    stacklevel=2,
-                )
-            val = val.astype(np.float32)
-        bucket = next((b for b in self.x_nnz_buckets if b >= idx.size), None)
-        if bucket is None:
-            x = np.zeros((n,), np.float32)
-            x[idx] = val
-            return self.submit(x)
-        req = EngineRequest(
-            rid=self._rid, x=(idx, val), t_submit=time.perf_counter(),
-            _engine=self,
-        )
-        self._rid += 1
-        self.stats.n_requests += 1
-        window = max(1, self.async_depth)
-        while len(self._inflight) >= window:
-            self._retire_one()
-        key = ("spmspv", bucket)
-        try:
-            ys, ok = self._launch(key, [req])
-        except Exception as exc:
-            self.flush()  # older batches retire first: FIFO holds under faults
-            self._recover([req], key, 1, exc)
+            n = self.shape[1]
+            idx, val = validate_sparse_rhs(indices, values, n)
+            val = np.asarray(val)
+            if val.dtype != np.float32:
+                if self.strict_dtype:
+                    raise TypeError(
+                        f"submit_sparse() got values dtype {val.dtype}; this "
+                        "engine serves float32 and strict_dtype=True forbids "
+                        "the implicit cast"
+                    )
+                if not self._dtype_warned:
+                    self._dtype_warned = True
+                    warnings.warn(
+                        f"SparseEngine.submit_sparse: casting {val.dtype} values "
+                        "to float32 (the engine's serving dtype) — submit "
+                        "float32 to avoid the cast, or build the engine with "
+                        "strict_dtype=True to make this an error; warning once "
+                        "per engine",
+                        stacklevel=2,
+                    )
+                val = val.astype(np.float32)
+            bucket = next((b for b in self.x_nnz_buckets if b >= idx.size), None)
+            if bucket is None:
+                x = np.zeros((n,), np.float32)
+                x[idx] = val
+                return self.submit(x)
+            req = EngineRequest(
+                rid=self._rid, x=(idx, val), t_submit=time.perf_counter(),
+                _engine=self,
+            )
+            self._rid += 1
+            self.stats.n_requests += 1
+            window = max(1, self.async_depth)
+            while len(self._inflight) >= window:
+                self._retire_one()
+            key = ("spmspv", bucket)
+            batch = self._next_batch()
+            try:
+                with TraceAnnotation("engine.launch", batch=batch, bucket=key,
+                                     take=1):
+                    ys, ok = self._launch(key, [req])
+            except Exception as exc:
+                self.flush()  # older batches retire first: FIFO holds under faults
+                self._recover([req], key, 1, exc, batch)
+                return req
+            self._inflight.append((ys, ok, [req], key, 1, batch))
+            if self.async_depth == 0:
+                self._retire_one()
             return req
-        self._inflight.append((ys, ok, [req], key, 1))
-        if self.async_depth == 0:
-            self._retire_one()
-        return req
 
     def _sparse_op(self, bucket: int) -> SparseOperator:
         op = self._sparse_ops.get(bucket)
@@ -861,52 +872,56 @@ class SparseEngine:
         as-is (rounded up to its bucket).  ``force=True`` (used by drain)
         bypasses the wait and flushes immediately.
         """
-        self._apply_pending_swap()  # dispatch boundary: adopt a staged table
-        if self._brownout is not None and self._brownout_update:
-            self._brownout.update(self._overload_pressure())
-        self._shed_lapsed()  # deadline shedding happens AT dispatch time
-        if not self._queue:
-            self._retire_ready()  # idle: resolve futures promptly
-            return 0
-        if (
-            not force
-            and self.max_wait_s is not None
-            and len(self._queue) < self.ks[-1]
-            and time.perf_counter() - self._queue[0].t_submit < self.max_wait_s
-        ):
-            # Held by the admission gate: use the wait to retire in-flight
-            # batches whose results are already on device, so their
-            # latency stats record availability, not bookkeeping lag.
-            self._retire_ready()
-            return 0
-        bucket, take = self._bucket_for(len(self._queue))
-        pop = self._queue.popleft
-        reqs = [pop() for _ in range(take)]
-        self._notify()  # queue space freed: wake submitters blocked on it
+        with TraceAnnotation("engine.step"):
+            self._apply_pending_swap()  # dispatch boundary: adopt a staged table
+            if self._brownout is not None and self._brownout_update:
+                self._brownout.update(self._overload_pressure())
+            self._shed_lapsed()  # deadline shedding happens AT dispatch time
+            if not self._queue:
+                self._retire_ready()  # idle: resolve futures promptly
+                return 0
+            if (
+                not force
+                and self.max_wait_s is not None
+                and len(self._queue) < self.ks[-1]
+                and time.perf_counter() - self._queue[0].t_submit < self.max_wait_s
+            ):
+                # Held by the admission gate: use the wait to retire in-flight
+                # batches whose results are already on device, so their
+                # latency stats record availability, not bookkeeping lag.
+                self._retire_ready()
+                return 0
+            bucket, take = self._bucket_for(len(self._queue))
+            pop = self._queue.popleft
+            reqs = [pop() for _ in range(take)]
+            self._notify()  # queue space freed: wake submitters blocked on it
 
-        if self.legacy_dispatch:
-            return self._step_legacy(reqs, bucket, take)
+            if self.legacy_dispatch:
+                return self._step_legacy(reqs, bucket, take)
 
-        # In-flight window: bound how far dispatch runs ahead of retirement
-        # (two-deep by default — batch t+1 assembles and launches while
-        # batch t computes; retirement stays FIFO).
-        window = max(1, self.async_depth)
-        while len(self._inflight) >= window:
-            self._retire_one()
+            # In-flight window: bound how far dispatch runs ahead of retirement
+            # (two-deep by default — batch t+1 assembles and launches while
+            # batch t computes; retirement stays FIFO).
+            window = max(1, self.async_depth)
+            while len(self._inflight) >= window:
+                self._retire_one()
 
-        try:
-            ys, ok = self._launch(bucket, reqs)
-        except Exception as exc:
-            # A dispatch-time failure must not reorder retirement: retire
-            # every older in-flight batch first, then recover this one
-            # synchronously (retry -> demote -> fail its futures).
-            self.flush()
-            self._recover(reqs, bucket, take, exc)
+            batch = self._next_batch()
+            try:
+                with TraceAnnotation("engine.launch", batch=batch,
+                                     bucket=bucket, take=take):
+                    ys, ok = self._launch(bucket, reqs)
+            except Exception as exc:
+                # A dispatch-time failure must not reorder retirement: retire
+                # every older in-flight batch first, then recover this one
+                # synchronously (retry -> demote -> fail its futures).
+                self.flush()
+                self._recover(reqs, bucket, take, exc, batch)
+                return take
+            self._inflight.append((ys, ok, reqs, bucket, take, batch))
+            if self.async_depth == 0:
+                self._retire_one()
             return take
-        self._inflight.append((ys, ok, reqs, bucket, take))
-        if self.async_depth == 0:
-            self._retire_one()
-        return take
 
     def _check_open(self) -> None:
         if self._closed:
@@ -942,7 +957,7 @@ class SparseEngine:
                 self._queue.popleft().set_exception(exc)
                 aborted += 1
             while self._inflight:
-                _ys, _ok, reqs, _bucket, take = self._inflight.popleft()
+                _ys, _ok, reqs, _bucket, take, _batch = self._inflight.popleft()
                 for req in reqs:
                     req.set_exception(exc)
                 aborted += take
@@ -976,11 +991,17 @@ class SparseEngine:
             xs.extend([self._zero] * (bucket - len(xs)))
         return tuple(xs)
 
+    def _next_batch(self) -> int:
+        batch = self._batch
+        self._batch += 1
+        return batch
+
     def _launch(self, bucket, reqs: list):
         """Assemble + dispatch one batch through the bucket's executable,
         firing any armed injection sites on the way; returns ``(ys, ok)``
         where ``ok`` is the on-device all-finite flag (None when the guard
-        is off)."""
+        is off).  Callers wrap it in the ``engine.launch`` span, whose
+        ``batch`` the batch's ``engine.retire`` span shares."""
         faults = self.faults
         if faults is not None:
             stall = faults.delay(
@@ -1034,6 +1055,7 @@ class SparseEngine:
                 (lambda x: body(x[:, None])) if bucket == 1 else body,
                 bucket=bucket,
                 guard=self.nan_guard,
+                name=f"engine_k{bucket}",
             )
         else:
             fn = self._make_exec(bucket, self.ops[bucket])
@@ -1056,14 +1078,17 @@ class SparseEngine:
                 self.a, op.plan.candidate, op._prep, k=op.plan.k,
                 mesh=self.mesh, axis=self.axis, donate_rhs=True,
             )
-            asm = fused_batch_executable(None, bucket=bucket)
+            asm = fused_batch_executable(
+                None, bucket=bucket, name=f"engine_mesh_slab_k{bucket}"
+            )
 
             def fn(*xs, _asm=asm, _run=run):
                 return _run(_asm(*xs))
 
             return finite_guard(fn) if self.nan_guard else fn
         return fused_batch_executable(
-            op._run, bucket=bucket, guard=self.nan_guard
+            op._run, bucket=bucket, guard=self.nan_guard,
+            name=f"engine_k{bucket}",
         )
 
     # -- retirement ---------------------------------------------------------
@@ -1071,28 +1096,30 @@ class SparseEngine:
         """Await the oldest in-flight batch; fill its futures + stats.
         A batch that failed on device (or flagged non-finite output) goes
         through :meth:`_recover` instead of filling futures."""
-        ys, ok, reqs, bucket, take = self._inflight.popleft()
-        exc: Exception | None = None
-        try:
-            ys.block_until_ready()
-            if ok is not None and not bool(ok):
-                exc = self._nonfinite(bucket)
-        except Exception as e:  # device-side failure surfaces at the block
-            exc = e
-        if exc is not None:
-            return self._recover(reqs, bucket, take, exc)
-        t_done = time.perf_counter()
-        lats = []
-        for i, req in enumerate(reqs):
-            req._ys = ys
-            req._col = i
-            req.t_done = t_done
-            req.bucket = bucket
-            lats.append(t_done - req.t_submit)
-        self.stats.record(bucket, take, lats)
-        self.consecutive_failures = 0
-        self._notify()  # futures resolved: wake callers blocked in result()
-        return take
+        ys, ok, reqs, bucket, take, batch = self._inflight.popleft()
+        with TraceAnnotation("engine.retire", batch=batch):
+            exc: Exception | None = None
+            try:
+                with TraceAnnotation("engine.device_wait"):
+                    ys.block_until_ready()
+                if ok is not None and not bool(ok):
+                    exc = self._nonfinite(bucket)
+            except Exception as e:  # device-side failure surfaces at the block
+                exc = e
+            if exc is not None:
+                return self._recover(reqs, bucket, take, exc, batch)
+            t_done = time.perf_counter()
+            lats = []
+            for i, req in enumerate(reqs):
+                req._ys = ys
+                req._col = i
+                req.t_done = t_done
+                req.bucket = bucket
+                lats.append(t_done - req.t_submit)
+            self.stats.record(bucket, take, lats)
+            self.consecutive_failures = 0
+            self._notify()  # futures resolved: wake callers blocked in result()
+            return take
 
     def _nonfinite(self, bucket) -> NonFiniteOutput:
         return NonFiniteOutput(
@@ -1102,7 +1129,8 @@ class SparseEngine:
         )
 
     # -- supervision: retry -> demote -> fail-the-futures -------------------
-    def _recover(self, reqs: list, bucket, take: int, exc: Exception) -> int:
+    def _recover(self, reqs: list, bucket, take: int, exc: Exception,
+                 batch: int) -> int:
         """Serve a failed batch through the supervision policy.
 
         Retries the current tier up to ``max_retries`` times with capped
@@ -1114,55 +1142,59 @@ class SparseEngine:
         retired, so FIFO retirement order and bitwise results of unaffected
         batches are untouched.
         """
-        sup = self.supervisor
-        sup.record(
-            "batch_failed", engine=self.name, bucket=bucket, error=repr(exc)
-        )
-        last: Exception = exc
-        attempt = 0
-        budget = sup.max_retries  # retries left on the current tier
-        while True:
-            if budget <= 0:
-                if not self._demote(bucket, last):
-                    break  # chain exhausted
-                budget = 1 + sup.max_retries  # fresh budget for the new tier
-            budget -= 1
-            sup.sleep(sup.backoff(attempt))
-            attempt += 1
-            self.stats.retries += 1
-            sup.retries += 1
-            try:
-                ys, ok = self._launch(bucket, reqs)
-                ys.block_until_ready()
-                if ok is not None and not bool(ok):
-                    raise self._nonfinite(bucket)
-            except Exception as e:
-                last = e
-                continue
-            t_done = time.perf_counter()
-            lats = []
-            for i, req in enumerate(reqs):
-                req._ys = ys
-                req._col = i
-                req.t_done = t_done
+        with TraceAnnotation("engine.recover", batch=batch):
+            sup = self.supervisor
+            sup.record(
+                "batch_failed", engine=self.name, bucket=bucket, error=repr(exc)
+            )
+            last: Exception = exc
+            attempt = 0
+            budget = sup.max_retries  # retries left on the current tier
+            while True:
+                if budget <= 0:
+                    if not self._demote(bucket, last):
+                        break  # chain exhausted
+                    budget = 1 + sup.max_retries  # fresh budget for the new tier
+                budget -= 1
+                sup.sleep(sup.backoff(attempt))
+                attempt += 1
+                self.stats.retries += 1
+                sup.retries += 1
+                try:
+                    with TraceAnnotation("engine.launch", batch=batch,
+                                         bucket=bucket, take=take):
+                        ys, ok = self._launch(bucket, reqs)
+                    with TraceAnnotation("engine.device_wait"):
+                        ys.block_until_ready()
+                    if ok is not None and not bool(ok):
+                        raise self._nonfinite(bucket)
+                except Exception as e:
+                    last = e
+                    continue
+                t_done = time.perf_counter()
+                lats = []
+                for i, req in enumerate(reqs):
+                    req._ys = ys
+                    req._col = i
+                    req.t_done = t_done
+                    req.bucket = bucket
+                    lats.append(t_done - req.t_submit)
+                self.stats.record(bucket, take, lats)
+                self.consecutive_failures = 0
+                self._notify()
+                return take
+            for req in reqs:
                 req.bucket = bucket
-                lats.append(t_done - req.t_submit)
-            self.stats.record(bucket, take, lats)
-            self.consecutive_failures = 0
-            self._notify()
+                req.set_exception(last)
+            self.stats.failed_batches += 1
+            self.stats.failed_requests += take
+            self.consecutive_failures += 1
+            sup.failures += 1
+            sup.record(
+                "batch_abandoned", engine=self.name, bucket=bucket,
+                n_requests=take, error=repr(last),
+            )
             return take
-        for req in reqs:
-            req.bucket = bucket
-            req.set_exception(last)
-        self.stats.failed_batches += 1
-        self.stats.failed_requests += take
-        self.consecutive_failures += 1
-        sup.failures += 1
-        sup.record(
-            "batch_abandoned", engine=self.name, bucket=bucket,
-            n_requests=take, error=repr(last),
-        )
-        return take
 
     def _demote(self, bucket, exc: Exception) -> bool:
         """Install the next fallback tier for one bucket; False when the
@@ -1198,7 +1230,8 @@ class SparseEngine:
                 # degrades to unsharded serving (correct, slower) because
                 # from_candidate tiers are single-device by construction.
                 fn = fused_batch_executable(
-                    op._run, bucket=bucket, guard=self.nan_guard
+                    op._run, bucket=bucket, guard=self.nan_guard,
+                    name=f"engine_k{bucket}_fallback{level}",
                 )
                 self.ops[bucket] = op
                 self._execs[bucket] = fn

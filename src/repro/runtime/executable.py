@@ -21,6 +21,17 @@ removes it:
   4 us for the argument-signature lookup in Python, and about 3 us for
   handing the arrays over, against a program with the arrays baked in.
 
+  ``name=`` labels the program: the jitted function is ``apply_<name>``,
+  so its module (the profiler's "XLA Modules" line, the HLO dump, the
+  compile log) is ``jit_apply_<name>`` and each bucket or solver program
+  can be told apart from the others.
+
+* :func:`compile_counts` — how many jit cache misses (jaxpr traces) and
+  backend compiles the process has run, with their seconds and the
+  compiled programs' names, from one ``jax.monitoring`` listener
+  registered at import.  A caller differences two snapshots to learn
+  whether a window of serving recompiled anything, and what.
+
 * :func:`aot_compile` — lower a function ONCE to an explicitly AOT-compiled
   executable over given shapes (used by ``SparseOperator.aot`` and the
   benchmarks' kernel-only baselines), with the same argument hoisting.
@@ -55,15 +66,60 @@ difference ``benchmarks/fig15_dispatch.py`` exists to count.
 """
 from __future__ import annotations
 
+import re
+import threading
 import warnings
+from collections import Counter
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["aot_compile", "fused_batch_executable", "finite_guard",
-           "hoisted_jit"]
+__all__ = ["aot_compile", "compile_counts", "fused_batch_executable",
+           "finite_guard", "hoisted_jit"]
+
+# jax.monitoring duration events: a jit cache miss traces a jaxpr; a
+# program the process does not hold yet goes through the backend (an XLA
+# compile, or a load from the persistent compilation cache).
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_counts_lock = threading.Lock()
+_counts = {"jaxpr_traces": 0, "jaxpr_trace_s": 0.0,
+           "backend_compiles": 0, "backend_compile_s": 0.0}
+_compiled_programs: Counter = Counter()
+
+
+def _count_compile(event: str, duration: float, **kwargs) -> None:
+    if event == JAXPR_TRACE_EVENT:
+        with _counts_lock:
+            _counts["jaxpr_traces"] += 1
+            _counts["jaxpr_trace_s"] += duration
+    elif event == BACKEND_COMPILE_EVENT:
+        with _counts_lock:
+            _counts["backend_compiles"] += 1
+            _counts["backend_compile_s"] += duration
+            _compiled_programs[str(kwargs.get("fun_name", "?"))] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+def compile_counts() -> dict:
+    """A snapshot of the process's compile counter.
+
+    ``jaxpr_traces``/``jaxpr_trace_s``: jit cache misses and the seconds
+    spent tracing them; ``backend_compiles``/``backend_compile_s``:
+    programs handed to the backend (compiled, or loaded from the
+    persistent cache); ``programs``: backend compiles by the name jax
+    gives the program (``jit(apply_engine_k16)`` for the module
+    ``jit_apply_engine_k16``).
+    Difference two snapshots to count what a window compiled; a warm
+    serving loop compiles nothing.
+    """
+    with _counts_lock:
+        return {**_counts, "programs": dict(_compiled_programs)}
 
 
 def _signature(xs) -> tuple:
@@ -100,13 +156,21 @@ def _hoist(fn: Callable, xs) -> tuple[Callable, list]:
     return apply, consts
 
 
-def hoisted_jit(fn: Callable, *, donate_argnums=()) -> Callable:
+_NAME = re.compile(r"[A-Za-z0-9_]+")
+
+
+def hoisted_jit(fn: Callable, *, name: str | None = None,
+                donate_argnums=()) -> Callable:
     """``jax.jit(fn)`` with closed-over arrays passed as arguments.
 
     Compiles once per positional-argument signature (shapes and dtypes);
     the prepared arrays ``fn`` closes over stay where they live on device
     and are handed to the program on every call, never copied into it.
+    ``name`` (letters, digits, ``_``) names the program ``jit_apply_<name>``
+    instead of ``jit_apply``.
     """
+    if name is not None and not _NAME.fullmatch(name):
+        raise ValueError(f"program name {name!r} must be letters, digits or _")
     progs: dict = {}
     donate = tuple(int(i) + 1 for i in donate_argnums)
 
@@ -115,6 +179,8 @@ def hoisted_jit(fn: Callable, *, donate_argnums=()) -> Callable:
         e = progs.get(key)
         if e is None:
             apply, consts = _hoist(fn, xs)
+            if name is not None:
+                apply.__name__ = apply.__qualname__ = f"apply_{name}"
             e = progs[key] = (jax.jit(apply, donate_argnums=donate), consts)
         return e
 
@@ -178,7 +244,8 @@ def finite_guard(fn: Callable) -> Callable:
 
 
 def fused_batch_executable(
-    run: Callable | None, *, bucket: int, guard: bool = False
+    run: Callable | None, *, bucket: int, guard: bool = False,
+    name: str | None = None,
 ) -> Callable:
     """Persistent compiled ``(x_0..x_{bucket-1}) -> ys`` for one bucket.
 
@@ -205,6 +272,9 @@ def fused_batch_executable(
     engine's supervisor treats a False flag as a fault (NaN/Inf outputs
     from a poisoned operand or a broken kernel).  Opt-in: the extra
     reduction is device work the default hot path does not pay.
+
+    ``name`` names the program (see :func:`hoisted_jit`): the engine's
+    tuned bucket programs are ``jit_apply_engine_k<bucket>``.
     """
     if bucket == 1:
 
@@ -219,4 +289,4 @@ def fused_batch_executable(
             ys = slab if run is None else run(slab)
             return (ys, jnp.isfinite(ys).all()) if guard else ys
 
-    return hoisted_jit(fn)
+    return hoisted_jit(fn, name=name)

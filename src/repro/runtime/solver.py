@@ -57,6 +57,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.formats import CSRMatrix
 from repro.runtime.executable import hoisted_jit
@@ -206,7 +207,9 @@ class SparseSolver:
         clear rebinds every program to the fallback operator)."""
         prog = self._progs.get(key)
         if prog is None:
-            prog = self._progs[key] = hoisted_jit(builder(self.op(k)._run))
+            prog = self._progs[key] = hoisted_jit(
+                builder(self.op(k)._run), name=f"solver_{key[0]}"
+            )
         return prog
 
     def _call(self, key: tuple, k: int, builder: Callable, *args):
@@ -221,52 +224,56 @@ class SparseSolver:
         faults (a converged-looking state full of NaN is worse than an
         exception).
         """
-        sup = self.supervisor
-        budget = sup.max_retries
-        attempt = 0
-        last: BaseException | None = None
-        while True:
-            try:
-                if self.faults is not None:
-                    self.faults.fire(
-                        "solver.dispatch", solver=key[0], k=k, name=self.name
-                    )
-                out = jax.block_until_ready(self._prog(key, k, builder)(*args))
-                if self.nan_guard:
-                    for leaf in jax.tree_util.tree_leaves(out):
-                        if jnp.issubdtype(
-                            leaf.dtype, jnp.floating
-                        ) and not bool(jnp.isfinite(leaf).all()):
-                            raise NonFiniteOutput(
-                                f"solver {key[0]!r} (k={k}) produced "
-                                "non-finite outputs"
-                            )
-                if attempt:
+        with TraceAnnotation("solver.call", solver=key[0], k=k):
+            sup = self.supervisor
+            budget = sup.max_retries
+            attempt = 0
+            last: BaseException | None = None
+            while True:
+                try:
+                    if self.faults is not None:
+                        self.faults.fire(
+                            "solver.dispatch", solver=key[0], k=k, name=self.name
+                        )
+                    with TraceAnnotation("solver.launch"):
+                        out = self._prog(key, k, builder)(*args)
+                    with TraceAnnotation("solver.device_wait"):
+                        jax.block_until_ready(out)
+                    if self.nan_guard:
+                        for leaf in jax.tree_util.tree_leaves(out):
+                            if jnp.issubdtype(
+                                leaf.dtype, jnp.floating
+                            ) and not bool(jnp.isfinite(leaf).all()):
+                                raise NonFiniteOutput(
+                                    f"solver {key[0]!r} (k={k}) produced "
+                                    "non-finite outputs"
+                                )
+                    if attempt:
+                        sup.record(
+                            "solver_recovered", solver=key[0], k=k, attempts=attempt
+                        )
+                    return out
+                except Exception as exc:
+                    last = exc
                     sup.record(
-                        "solver_recovered", solver=key[0], k=k, attempts=attempt
+                        "solver_attempt_failed",
+                        solver=key[0],
+                        k=k,
+                        error=repr(exc),
                     )
-                return out
-            except Exception as exc:
-                last = exc
-                sup.record(
-                    "solver_attempt_failed",
-                    solver=key[0],
-                    k=k,
-                    error=repr(exc),
-                )
-                if budget > 0:
-                    budget -= 1
-                    sup.retries += 1
-                    sup.sleep(sup.backoff(attempt))
-                    attempt += 1
-                    continue
-                if self._demote(key[0], k, exc):
-                    budget = sup.max_retries
-                    attempt += 1
-                    continue
-                sup.failures += 1
-                sup.record("solver_failed", solver=key[0], k=k, error=repr(exc))
-                raise last
+                    if budget > 0:
+                        budget -= 1
+                        sup.retries += 1
+                        sup.sleep(sup.backoff(attempt))
+                        attempt += 1
+                        continue
+                    if self._demote(key[0], k, exc):
+                        budget = sup.max_retries
+                        attempt += 1
+                        continue
+                    sup.failures += 1
+                    sup.record("solver_failed", solver=key[0], k=k, error=repr(exc))
+                    raise last
 
     def _demote(self, solver: str, k: int, exc: BaseException) -> bool:
         """Walk width k's plan one tier down the fallback chain.
@@ -338,11 +345,13 @@ class SparseSolver:
             self._x0(x0, b.shape),
             jnp.float32(tol),
         )
+        with TraceAnnotation("solver.fetch"):
+            it, res, conv = int(it), float(res), bool(conv)
         return SolverResult(
             solver="cg",
-            iterations=int(it),
-            residual=float(res),
-            converged=bool(conv),
+            iterations=it,
+            residual=res,
+            converged=conv,
             plan=self.op(1).plan.candidate.key(),
             x=x,
         )
@@ -369,15 +378,14 @@ class SparseSolver:
             v0 = jnp.asarray(rng.standard_normal(n).astype(np.float32))
         else:
             v0 = jnp.asarray(v0, jnp.float32)
-        alphas, betas = (
-            np.asarray(v)
-            for v in self._call(
-                ("lanczos", int(num_steps)),
-                1,
-                lambda run: _make_lanczos_prog(run, self._dot, int(num_steps)),
-                v0,
-            )
+        out = self._call(
+            ("lanczos", int(num_steps)),
+            1,
+            lambda run: _make_lanczos_prog(run, self._dot, int(num_steps)),
+            v0,
         )
+        with TraceAnnotation("solver.fetch"):
+            alphas, betas = (np.asarray(v) for v in out)
         ritz = tridiag_eigvalsh(alphas, betas[:-1]) if num_steps > 1 else alphas
         return SolverResult(
             solver="lanczos",
@@ -426,13 +434,16 @@ class SparseSolver:
             v0,
             jnp.float32(tol),
         )
+        with TraceAnnotation("solver.fetch"):
+            it, diff, conv = int(it), float(diff), bool(conv)
+            theta = np.asarray(theta)
         return SolverResult(
             solver="block_power",
-            iterations=int(it),
-            residual=float(diff),
-            converged=bool(conv),
+            iterations=it,
+            residual=diff,
+            converged=conv,
             plan=self.op(k).plan.candidate.key(),
-            eigenvalues=np.asarray(theta),
+            eigenvalues=theta,
             eigenvectors=V,
         )
 

@@ -503,10 +503,11 @@ def sparse_rhs_runner(
         return spmspv_bind(prep, bucket, impl=cand.impl, **cand.param_dict)
     base = runner(a, cand, prep, k=1)
 
-    @hoisted_jit
-    def densified(xi, xv):
+    def densify(xi, xv):
         x = jnp.zeros((n,), xv.dtype).at[xi].add(xv, mode="drop")
         return base(x)
+
+    densified = hoisted_jit(densify, name="sparse_rhs_densify")
 
     def fn(sx):
         xi, xv = sx

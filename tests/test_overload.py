@@ -26,7 +26,7 @@ from repro.runtime.overload import (
     OverloadError,
     TokenBucket,
 )
-from repro.tune import PlanCache, time_fn
+from repro.tune import PlanCache
 
 
 def small(seed=0, m=128, density=0.06):
@@ -314,9 +314,12 @@ def test_fair_share_greedy_cannot_starve_polite():
     # compile both tenants outside the measured loop
     fleet.submit("polite", xp[0]); fleet.submit("greedy", xg[0])
     fleet.drain()
-    op4 = fleet.tenants["polite"].engine.ops[4]
-    quantum = time_fn(op4._run, jnp.stack(xp[:4], axis=1), warmup=1, timed=3)
-    lats, limited = [], 0
+    engines = [fleet.tenants[name].engine for name in ("greedy", "polite")]
+
+    def dispatches():
+        return sum(e.stats.n_dispatches for e in engines)
+
+    quanta, limited = [], 0
     for j in range(24):
         for b in range(8):  # greedy offers an 8x burst every round...
             try:
@@ -324,20 +327,25 @@ def test_fair_share_greedy_cannot_starve_polite():
             except OverloadError:
                 limited += 1  # ...and its excess fails fast, typed
         r = fleet.submit("polite", xp[j % 8])
+        before = dispatches()
         while r._ys is None:
             if fleet.step() == 0:
                 fleet.flush()
-        lats.append(r.latency_s)
+        # The polite request's wait in service quanta: the batches the
+        # fleet's engines dispatched between its submit and its retirement
+        # (its own included) -- counted by the engines, so the bound holds
+        # however busy the CPU running the test is.
+        quanta.append(dispatches() - before)
     fleet.drain()
     assert limited > 0  # the bucket actually bit
     assert fleet.stats_fleet.rate_limited == limited
-    p99 = float(np.quantile(np.asarray(lats), 0.99))
-    # fig18/fig19's SLO budget shape: SLO + bounded service quanta.  The
-    # greedy tenant's admitted trickle may interleave, but its REFUSED
-    # burst must never show up in the polite tenant's tail.
-    assert p99 <= slo + 16 * quantum + 0.05, (
-        f"polite p99 {p99 * 1e3:.1f}ms blew the budget "
-        f"(quantum {quantum * 1e3:.2f}ms, {limited} greedy refusals)")
+    p99 = float(np.quantile(np.asarray(quanta), 0.99))
+    # fig18/fig19's SLO budget shape: bounded service quanta.  The greedy
+    # tenant's admitted trickle may interleave, but its REFUSED burst must
+    # never show up in the polite tenant's tail.
+    assert p99 <= 16, (
+        f"polite p99 wait of {p99:.1f} dispatches blew the 16-quantum "
+        f"budget ({limited} greedy refusals)")
     fleet.close()
 
 
