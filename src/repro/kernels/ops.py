@@ -9,11 +9,13 @@ slab pipeline (kernels/pipeline.py).
 
 On TPU every kernel here compiles for v5e at Table-1 sizes
 (``tests/test_tpu_compile.py`` compiles each one for a described v5e
-topology): SELL SpMV resident and column-slab, BCSR SpMM at k = 1 and 16,
-and the SpMSpV scatter.  None is taken out of the TPU search space for a
-compiler refusal; ``tune.candidates.enumerate_candidates`` enumerates each
-only where its resident operands fit :data:`VMEM_BUDGET_BYTES` (and the
-SELL tiles fit SMEM), which is a size rule, not a refusal.
+topology): SELL SpMV resident and column-slab, lane-row SELL SpMV, BCSR
+SpMM at k = 1 and 16, and the SpMSpV scatter.  None is taken out of the
+TPU search space for a compiler refusal;
+``tune.candidates.enumerate_candidates`` enumerates each only where its
+resident operands fit :data:`VMEM_BUDGET_BYTES` (and the SELL tiles fit
+SMEM, and lane-row SELL's value rows a quarter of HBM), which is a size
+rule, not a refusal.
 """
 from __future__ import annotations
 
@@ -27,8 +29,13 @@ import numpy as np
 from repro.core.formats import BCSRMatrix, SELLMatrix
 from repro.core.formats import nnz_row_ids as formats_nnz_row_ids
 from .bcsr_spmm import TILE_ROWS, bcsr_spmm_pallas
-from .pipeline import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
-from .sell_spmv import CHUNK_ALIGN, sell_spmv_blocked_pallas, sell_spmv_pallas
+from .pipeline import LANES, VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
+from .sell_spmv import (
+    CHUNK_ALIGN,
+    sell_lr_spmv_pallas,
+    sell_spmv_blocked_pallas,
+    sell_spmv_pallas,
+)
 
 __all__ = [
     "on_cpu",
@@ -40,6 +47,9 @@ __all__ = [
     "sell_prepare_blocked_stacked",
     "sell_spmv_blocked",
     "sell_spmv_blocked_stacked",
+    "lanerow_counts",
+    "sell_lr_prepare",
+    "sell_lr_spmv",
     "VMEM_BUDGET_BYTES",
     "VMEM_LIMIT_BYTES",
 ]
@@ -191,6 +201,128 @@ def sell_spmv(
         prep["cols"], prep["vals"], prep["row_perm"], x,
         n_rows=m, chunk_tile=int(prep.get("chunk_tile", 8)),
         interpret=interpret,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Lane-row SELL: one segment per (row, 128-column lane-row of x)
+# ---------------------------------------------------------------------------
+def _lane_row_segments(a) -> tuple[np.ndarray | None, np.ndarray, np.ndarray, int]:
+    """A's nonzeros grouped into (row, ``col >> 7``) segments.
+
+    Returns ``(order, key, first, n_lr)``: ``order`` sorts A's nonzeros by
+    (row, column) (None where A's columns already ascend within rows),
+    ``key`` is each sorted nonzero's ``row * n_lr + (col >> 7)``, ``first``
+    marks each segment's first nonzero, and ``n_lr`` is x's number of
+    128-lane rows.
+    """
+    n = max(int(a.shape[1]), 1)
+    n_lr = -(-n // LANES)
+    rows = formats_nnz_row_ids(a.indptr, dtype=np.int64)
+    cols = a.indices.astype(np.int64)
+    pos = rows * n + cols
+    order = None
+    if pos.size and np.any(pos[1:] < pos[:-1]):
+        order = np.argsort(pos, kind="stable")
+        rows, cols = rows[order], cols[order]
+    key = rows * n_lr + (cols >> 7)
+    first = np.ones(key.size, dtype=bool)
+    first[1:] = key[1:] != key[:-1]
+    return order, key, first, n_lr
+
+
+def lanerow_counts(a) -> np.ndarray:
+    """Segments per row: how many 128-column lane-rows of x each row of A
+    touches.  Their maximum is lane-row SELL's width W_seg."""
+    _, key, first, n_lr = _lane_row_segments(a)
+    return np.bincount(key[first] // n_lr, minlength=a.shape[0]).astype(np.int64)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots",))
+def _lane_rows_from_coo(flat, vals, *, n_slots):
+    """(n_slots, 128) value rows from (flat position, value) pairs sorted
+    by position, built on the device: the rows are 128 / nnz-per-segment
+    times the values' bytes, so only the compact pairs cross the host
+    link.  Sorted positions also keep the TPU compile of this scatter
+    under a second at ldoor's size (about 24 s unsorted)."""
+    out = jnp.zeros((n_slots * LANES,), vals.dtype).at[flat].add(
+        vals, indices_are_sorted=True
+    )
+    return out.reshape(n_slots, LANES)
+
+
+def sell_lr_prepare(a, C: int = 8, chunk_tile: int = 16) -> dict[str, Any]:
+    """Pack A as lane-row SELL (``kernels.sell_spmv.sell_lr_spmv_pallas``).
+
+    Each row's nonzeros are grouped by ``col >> 7`` into segments.  Rows
+    stay in their order (no sorting window) and are packed into chunks of
+    ``C``; every row gets one global width W_seg (the most segments any
+    row has, rounded up to 8) and the chunk count is padded to a multiple
+    of CHUNK_ALIGN.  ``seg_row`` (n_chunks * C * W_seg,), flat, holds each
+    segment's lane-row (padding: lane-row 0); ``seg_vals`` (n_chunks * C *
+    W_seg, 128) holds its values at lane ``col & 127`` and zeros elsewhere
+    (padding: a zero row).  The host computes (position, value) pairs of
+    nnz length; the device scatters them into the rows.
+    """
+    m, _ = a.shape
+    order, key, first, n_lr = _lane_row_segments(a)
+    vals = np.asarray(a.data)
+    lanes = a.indices.astype(np.int64) & (LANES - 1)
+    if order is not None:
+        vals, lanes = vals[order], lanes[order]
+    seg_key = key[first]
+    seg_rows = seg_key // n_lr
+    counts = np.bincount(seg_rows, minlength=m).astype(np.int64)
+    W = max(int(counts.max(initial=0)), 1)
+    W = -(-W // 8) * 8
+    n_chunks = max(1, -(-m // C))
+    align = max(CHUNK_ALIGN, chunk_tile)
+    n_chunks = -(-n_chunks // align) * align
+    n_slots = n_chunks * C * W
+    assert n_slots * LANES < 2**31, ("lane-row SELL too large", n_slots)
+
+    row_start = np.cumsum(counts) - counts  # first segment of each row
+    seg_pos = seg_rows * W + np.arange(seg_key.size) - row_start[seg_rows]
+    seg_row = np.zeros(n_slots, dtype=np.int32)
+    seg_row[seg_pos] = seg_key - seg_rows * n_lr
+    # Nonzeros sorted by (row, column) give ascending positions.
+    flat = (seg_pos[np.cumsum(first) - 1] * LANES + lanes).astype(np.int32)
+    return {
+        "seg_row": jnp.asarray(seg_row),
+        "seg_vals": _lane_rows_from_coo(
+            jnp.asarray(flat), jnp.asarray(vals), n_slots=n_slots
+        ),
+        "shape": a.shape,
+        "width": W,
+        "C": int(C),
+        "chunk_tile": int(chunk_tile),
+    }
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_rows", "width", "C", "chunk_tile", "interpret"),
+)
+def _sell_lr_spmv_jit(seg_row, seg_vals, x, *, n_rows, width, C, chunk_tile,
+                      interpret):
+    sums = sell_lr_spmv_pallas(
+        seg_row, seg_vals, x, width=width, C=C, chunk_tile=chunk_tile,
+        interpret=interpret,
+    )
+    return sums[:n_rows]
+
+
+def sell_lr_spmv(
+    prep: dict[str, Any], x: jax.Array, *, interpret: bool | None = None
+) -> jax.Array:
+    """y = A @ x through lane-row SELL: its rows are A's, in order."""
+    if interpret is None:
+        interpret = on_cpu()
+    m, _ = prep["shape"]
+    return _sell_lr_spmv_jit(
+        prep["seg_row"], prep["seg_vals"], x,
+        n_rows=m, width=int(prep["width"]), C=int(prep["C"]),
+        chunk_tile=int(prep["chunk_tile"]), interpret=interpret,
     )
 
 

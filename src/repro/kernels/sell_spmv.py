@@ -33,6 +33,36 @@ included); on TPU these kernels compile and compete in the measured
 search against XLA's own gathers, which are no faster per element.
 The UTD metric (core.metrics) predicts these kernels' win over the scalar
 tier exactly as UCLD predicts the vgatherd win in Fig 5.
+
+:func:`sell_lr_spmv_pallas` — lane-row SELL, the k = 1 tier whose unit of
+  work is a (row, x lane-row) *segment*, not a stored nonzero.  On a v5e
+  the SELL kernel's time follows its x accesses (about 5.5 ns a stored
+  slot on the ldoor surrogate), not its bytes.  A row's nonzeros that
+  share one 128-column lane-row of x need only that one dynamic sublane
+  load, so the packing (``ops.sell_lr_prepare``) groups each row's
+  nonzeros by ``col >> 7`` and stores one 128-lane value row per segment,
+  each value at lane ``col & 127`` and zeros elsewhere; rows keep their
+  order, so the output needs no un-permute:
+
+    seg_row  : ANY (HBM) -> SMEM tiles of T chunks    # lane-row per segment
+    seg_vals : ANY (HBM) -> VMEM slabs of T*C*W rows  # (slots, 128) values
+    x        : (n/128, 128) VMEM                      # resident
+    y        : (m/128, 128) VMEM                      # written once
+
+  Per segment: one x row load and one multiply-add, no mask; a row's
+  value rows come in 8 at a time as one aligned (8, 128) block.  The same
+  float32 products are summed, in another order inside a row.  The price
+  is 512 bytes of memory per segment slot, so the tuner enumerates the
+  tier on TPU only where a tile of ``seg_row`` fits SMEM and the value
+  rows fit a quarter of HBM (``tune.candidates.tpu_pallas_fits``), and
+  anywhere only within the prepared-operand budget its caller gives
+  (``SparseFleet`` gives a quarter of its residency budget).  That admits
+  rows that touch a few lane-rows each, as the ldoor surrogate's
+  generated pattern does (at most 8; ``pattern`` is a ``reduced`` key of
+  its configuration), never power-law rows that touch thousands.  Whether
+  the published ldoor would be admitted is not known: it is symmetric,
+  and the surrogate's symmetric A + A^T (what ``ldoor.cg`` solves)
+  touches up to 20 lane-rows a row, W_seg 24, which the HBM rule excludes.
 """
 from __future__ import annotations
 
@@ -56,8 +86,8 @@ from .pipeline import (
     slab_pipeline,
 )
 
-__all__ = ["sell_spmv_pallas", "sell_spmv_blocked_pallas", "smem_tile_slots",
-           "CHUNK_ALIGN"]
+__all__ = ["sell_spmv_pallas", "sell_spmv_blocked_pallas", "sell_lr_spmv_pallas",
+           "smem_tile_slots", "CHUNK_ALIGN"]
 
 
 # The SELL preparations pad the chunk count to this multiple (1024 rows).
@@ -217,3 +247,93 @@ def sell_spmv_blocked_pallas(
         cols, vals, x_rows,
         chunk_tile=chunk_tile, interpret=interpret, pipelined=pipelined,
     )
+
+
+def _tree_sum(terms):
+    """Pairwise sum: the adds of one unrolled group do not wait in a chain."""
+    while len(terms) > 1:
+        terms = [terms[i] + terms[i + 1] if i + 1 < len(terms) else terms[i]
+                 for i in range(0, len(terms), 2)]
+    return terms[0]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("width", "C", "chunk_tile", "interpret", "pipelined"),
+)
+def sell_lr_spmv_pallas(
+    seg_row: jax.Array,  # (n_chunks * C * W,) int32: each segment's lane-row
+    seg_vals: jax.Array,  # (n_chunks * C * W, 128): a segment's values by lane
+    x: jax.Array,  # (n,)
+    *,
+    width: int,
+    C: int = 8,
+    chunk_tile: int = 16,
+    interpret: bool = False,
+    pipelined: bool | None = None,
+) -> jax.Array:
+    """Lane-row SELL SpMV: per-row sums (n_chunks * C,), rows in A's order
+    and padding rows last.  Segment ``(chunk, c, w)`` sits at flat slot
+    ``(chunk * C + c) * width + w``.  ``seg_row`` stays flat: an int32
+    array with a minor dimension of 8 is laid out 128 lanes wide in HBM,
+    and flattening it would be a copy of 16 times its size every call.
+    Padding segments point at lane-row 0 with a zero value row, so they
+    add ``0 * x[0:128]``."""
+    W = int(width)
+    assert W % 8 == 0, W
+    n_chunks = seg_row.shape[0] // (C * W)
+    assert seg_row.shape == (n_chunks * C * W,), (seg_row.shape, C, W)
+    assert seg_vals.shape == (n_chunks * C * W, LANES), seg_vals.shape
+    T = _tile_chunks(chunk_tile, C, W)
+    assert n_chunks % T == 0, (n_chunks, T)
+    L = T * C * W  # segment slots in one tile of both streams
+    n_out = n_chunks * C
+    out_rows = lane_dense(jnp.zeros((n_out,), jnp.float32)).shape[0]
+    pipe = resolve_pipelined(pipelined, interpret)
+
+    def _kernel(r_hbm, v_hbm, x_ref, o_ref):
+        o_ref[...] = jnp.zeros_like(o_ref)
+        lane = lane_iota()
+
+        def tile(t, r_ref, v_ref):  # r_ref: SMEM (L,); v_ref: VMEM (L, 128)
+            def row(r, _):  # r-th sorted row of this tile
+                base = r * W
+
+                def segs(jj, acc):  # 8 segments per trip, unrolled
+                    j0 = pl.multiple_of(base + jj * 8, 8)
+                    vblk = v_ref[pl.ds(j0, 8), :]  # one aligned (8, 128) load
+                    prods = []
+                    for u in range(8):
+                        xr = x_ref[pl.ds(r_ref[j0 + u], 1), :]
+                        prods.append(xr * vblk[u:u + 1, :])
+                    return acc + _tree_sum(prods)
+
+                acc = jax.lax.fori_loop(
+                    0, W // 8, segs, jnp.zeros((1, LANES), jnp.float32)
+                )
+                total = jnp.sum(acc, axis=1, keepdims=True)
+                add_to_elem(o_ref, t * (T * C) + r, total, lane)
+                return 0
+
+            jax.lax.fori_loop(0, T * C, row, 0)
+
+        slab_pipeline(
+            tile,
+            [(r_hbm, L, SMEM), (v_hbm, L)],
+            n_chunks // T,
+            pipelined=pipe,
+        )
+
+    out = pl.pallas_call(
+        _kernel,
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),  # streamed by the pipeline
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # x, resident
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((out_rows, LANES), jnp.float32),
+        compiler_params=_CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(seg_row, seg_vals.astype(jnp.float32), lane_dense(x.astype(jnp.float32)))
+    return out.reshape(-1)[:n_out].astype(seg_vals.dtype)

@@ -249,6 +249,7 @@ class EngineStats:
     rejected: int = 0
     shed_oldest: int = 0
     shed_deadline: int = 0
+    _engine: Any = dataclasses.field(default=None, repr=False, compare=False)
 
     def record(self, bucket, n_real: int, lats: Iterable[float]) -> None:
         self.n_dispatches += 1
@@ -282,8 +283,11 @@ class EngineStats:
         return self.padded_cols / total if total else 0.0
 
     def summary(self) -> dict[str, Any]:
+        """The counters; with the engine, also each dense bucket's x row
+        loads per nonzero (``SparseOperator.x_loads_per_nnz``), where its
+        plan has one."""
         lats = np.asarray(self.latencies_s) if self.latencies_s else np.zeros(1)
-        return {
+        out = {
             "requests": self.n_requests,
             "dispatches": self.n_dispatches,
             "by_bucket": dict(sorted(self.dispatched.items())),
@@ -303,6 +307,12 @@ class EngineStats:
             "shed_oldest": self.shed_oldest,
             "shed_deadline": self.shed_deadline,
         }
+        if self._engine is not None:
+            loads = {b: op.x_loads_per_nnz for b, op in self._engine.ops.items()}
+            out["x_loads_per_nnz"] = {
+                b: round(v, 4) for b, v in sorted(loads.items()) if v is not None
+            }
+        return out
 
 
 class SparseEngine:
@@ -507,7 +517,7 @@ class SparseEngine:
         # (also the legacy path's pad column).
         self._zero = jnp.zeros((self.shape[1],), jnp.float32)
         self._nan_col = None  # lazy poisoned column for the engine.nan site
-        self.stats = EngineStats()
+        self.stats = EngineStats(_engine=self)
         # Degraded-mode state: bucket -> fallback-chain level (1-based), and
         # the saved tuned (op, exec) the repair thread probes/re-promotes.
         self._closed = False

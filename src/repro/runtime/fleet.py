@@ -31,7 +31,10 @@ dropped and their fingerprints purged from the global prepared-dict memo
 (:func:`repro.tune.evict_prepared`).  An evicted tenant is re-admitted on
 its next ``submit`` — by then retune has usually persisted its measured
 plans, so reactivation is an exact cache hit: eviction costs re-prepare,
-never re-search.
+never re-search.  Admission and retune plan each tenant within
+``prep_budget``, a quarter of the byte budget: a tier whose prepared
+operand outgrows the matrix (lane-row SELL, 512 bytes a segment slot) is
+chosen only where it leaves room for other tenants.
 
 **Cross-tenant scheduling.**  ``step()`` serves every tenant with work,
 deadline-first: tenants are ordered by their oldest pending request's SLO
@@ -361,6 +364,12 @@ class SparseFleet:
         return sum(t.nbytes for t in self._tenants.values() if t.resident)
 
     @property
+    def prep_budget(self) -> int:
+        """The most bytes one tenant's prepared operand may take, a quarter
+        of the budget (``SparseOperator.build(prep_budget=)``)."""
+        return self.budget_bytes // 4
+
+    @property
     def tenants(self) -> dict[str, Tenant]:
         return dict(self._tenants)
 
@@ -454,7 +463,8 @@ class SparseFleet:
         ops: dict[int, SparseOperator] = {}
         for k in self.ks:
             op = SparseOperator.build_predicted(
-                tenant.a, k=None if k == 1 else k, cache=self.cache
+                tenant.a, k=None if k == 1 else k, cache=self.cache,
+                prep_budget=self.prep_budget,
             )
             ops[k] = op
             if op.from_cache:
@@ -619,7 +629,8 @@ class SparseFleet:
         if self.faults is not None:
             self.faults.fire("fleet.retune", tenant=name)
         ops = SparseOperator.build_multi(
-            tenant.a, ks=self.ks, cache=self.cache, **self.retune_kwargs
+            tenant.a, ks=self.ks, cache=self.cache,
+            **{"prep_budget": self.prep_budget, **self.retune_kwargs},
         )
         eng = tenant.engine
         if eng is None:

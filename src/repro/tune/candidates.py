@@ -3,10 +3,11 @@
 A *candidate* is one (format, impl, params) point from the cross-product the
 paper sweeps by hand: CSR scalar/vector (Fig 4's -O1/-O3 tiers), SELL-C-sigma
 with sigma in {1, 64, 256} and resident vs column-slabbed x (Fig 5 / cache
-blocking), BCSR with the Table 2 block shapes, and the nnz-balanced merge
-tier (kernels/merge_spmv) whose chunked-scan decomposition is immune to
-row-length skew — the search-space answer to the paper's ``dynamic,64``
-load balancing.
+blocking), lane-row SELL (one Pallas candidate at k = 1: one x access per
+128-column segment of a row), BCSR with the Table 2 block shapes, and the
+nnz-balanced merge tier (kernels/merge_spmv) whose chunked-scan
+decomposition is immune to row-length skew — the search-space answer to
+the paper's ``dynamic,64`` load balancing.
 
 Pruning happens *before* any format is materialized or timed, from a cost
 model in abstract byte units: the paper's §4.2 application-bytes model per
@@ -52,6 +53,10 @@ __all__ = [
     "SOLVER_STEP_AMORTIZE",
     "SOLVER_VEC_PASSES",
     "SMEM_BUDGET_BYTES",
+    "HBM_BUDGET_BYTES",
+    "SELL_LR",
+    "fits_prep_budget",
+    "sell_lr_value_bytes",
     "tpu_pallas_fits",
 ]
 
@@ -116,7 +121,7 @@ class Candidate:
     """One point of the search space; params is a sorted tuple of pairs so
     the dataclass stays hashable (dict-valued params would not be)."""
 
-    fmt: str  # csr | sell | sell_blocked | bcsr | dist (mesh schedules)
+    fmt: str  # csr | sell | sell_lr | sell_blocked | bcsr | dist (mesh)
     impl: str  # scalar | vector | ref | pallas; for dist: allgather | ring
     params: tuple = ()
 
@@ -158,10 +163,42 @@ def split_reorder(cand: Candidate) -> tuple[str | None, Candidate]:
 # (rows, 128) vectors it indexes element-wise in VMEM, its x window, and
 # the SELL kernel's double-buffered (cols, vals) tiles in SMEM (1 MiB).
 SMEM_BUDGET_BYTES = 512 * 1024
+# Lane-row SELL stores a 128-lane value row per segment slot: 512 bytes in
+# HBM where SELL stores 8.  The tier may take a quarter of a v5e's 16 GB.
+HBM_BUDGET_BYTES = 4 * 1024 ** 3
+
+# The one lane-row SELL candidate (kernels/sell_spmv.sell_lr_spmv_pallas).
+SELL_LR = dict(C=8, chunk_tile=16)
 
 
 def _lane_dense_bytes(n: int) -> int:
     return -(-max(int(n), 1) // 1024) * 1024 * 4
+
+
+def sell_lr_value_bytes(cand: Candidate, feats: MatrixFeatures) -> int:
+    """Bytes of a lane-row SELL candidate's value rows (``seg_vals`` of
+    ``ops.sell_lr_prepare``): 128 float32 lanes per segment slot, with
+    W = W_seg rounded up to 8 and the chunk count padded to CHUNK_ALIGN."""
+    from repro.kernels.sell_spmv import CHUNK_ALIGN
+
+    p = cand.param_dict
+    C, ct = int(p.get("C", 8)), int(p.get("chunk_tile", 16))
+    W = -(-max(int(feats.lanerow_max), 1) // 8) * 8
+    align = max(CHUNK_ALIGN, ct)
+    n_chunks = -(-max(-(-int(feats.m) // C), 1) // align) * align
+    return n_chunks * C * W * 128 * 4
+
+
+def fits_prep_budget(
+    cand: Candidate, feats: MatrixFeatures, prep_budget: int | None
+) -> bool:
+    """Whether a candidate's prepared operand fits the caller's budget
+    (None: no budget).  Only lane-row SELL's can outgrow one: 512 bytes a
+    segment slot, where every other tier's operand is a few bytes a
+    nonzero, as the matrix itself is."""
+    if prep_budget is None or split_reorder(cand)[1].fmt != "sell_lr":
+        return True
+    return sell_lr_value_bytes(split_reorder(cand)[1], feats) <= prep_budget
 
 
 def tpu_pallas_fits(cand: Candidate, feats: MatrixFeatures) -> bool:
@@ -186,6 +223,15 @@ def tpu_pallas_fits(cand: Candidate, feats: MatrixFeatures) -> bool:
             -(-feats.n // n_slabs)
         )
         return smem <= SMEM_BUDGET_BYTES and vmem <= VMEM_BUDGET_BYTES
+    if cand.fmt == "sell_lr":
+        ct, C = int(p.get("chunk_tile", 16)), int(p.get("C", 8))
+        tile = smem_tile_slots(ct, feats.lanerow_max, C)
+        smem = 2 * 4 * tile  # the double-buffered seg_row tiles
+        slabs = 2 * tile * 128 * 4  # the double-buffered seg_vals slabs
+        vmem = _lane_dense_bytes(feats.m) + _lane_dense_bytes(feats.n)
+        return (smem <= SMEM_BUDGET_BYTES and vmem <= VMEM_BUDGET_BYTES
+                and slabs <= VMEM_BUDGET_BYTES
+                and sell_lr_value_bytes(cand, feats) <= HBM_BUDGET_BYTES)
     if cand.fmt == "bcsr":
         bm, bk = p["block"]
         window = TILE_ROWS + 2 * int(feats.bandwidth) + 2 * int(bk)
@@ -207,13 +253,15 @@ def enumerate_candidates(
     include_scalar: bool = True,
     include_pallas: bool = True,
     reorders: Iterable[str] = (),
+    prep_budget: int | None = None,
 ) -> list[Candidate]:
     """The format x impl x params cross-product for one matrix.
 
-    SELL and the scalar tier only exist for SpMV (kind="spmv"); SpMM
-    (kind="spmm") contrasts CSR gather/segment-sum with the Table 2 BCSR
-    shapes.  Column-slabbed SELL variants are enumerated only when the x
-    footprint exceeds the VMEM budget (features.x_fits_vmem).  The merge
+    SELL, lane-row SELL and the scalar tier only exist for SpMV
+    (kind="spmv"); SpMM (kind="spmm") contrasts CSR gather/segment-sum
+    with the Table 2 BCSR shapes.  Column-slabbed SELL variants are
+    enumerated only when the x footprint exceeds the VMEM budget
+    (features.x_fits_vmem).  The merge
     tier (nnz-balanced segmented scan, kernels/merge_spmv) enumerates for
     both kinds — it is the only tier whose work decomposition ignores the
     row distribution, so it is what the search falls back on when
@@ -235,7 +283,9 @@ def enumerate_candidates(
     reordering cannot rescue an unvectorized inner loop.
 
     On TPU the Pallas candidates are kept only where
-    :func:`tpu_pallas_fits` admits them (see :func:`_fit_backend`).
+    :func:`tpu_pallas_fits` admits them (see :func:`_fit_backend`), and on
+    any backend only those whose prepared operand fits ``prep_budget``
+    bytes, where the caller gives one (:func:`fits_prep_budget`).
     """
     if kind == "solver_step":
         kind = "spmv" if int(k) == 1 else "spmm"
@@ -259,6 +309,7 @@ def enumerate_candidates(
             include_scalar=False,
             include_pallas=include_pallas,
             reorders=(),
+            prep_budget=prep_budget,
         )
         cands.append(make("spmspv", "ref"))
         if include_pallas:
@@ -276,6 +327,8 @@ def enumerate_candidates(
                     cands.append(
                         make("sell", "pallas", C=8, sigma=sigma, chunk_tile=ct)
                     )
+        if include_pallas:
+            cands.append(make("sell_lr", "pallas", **SELL_LR))
         if not feats.x_fits_vmem:
             # Column-slab SELL is an SpMV tier: its slabs split x, which at
             # k > 1 would be an (n, k) slab it has no kernel for.
@@ -312,6 +365,7 @@ def enumerate_candidates(
             cands.extend(
                 make(c.fmt, c.impl, reorder=method, **c.param_dict) for c in base
             )
+    cands = [c for c in cands if fits_prep_budget(c, feats, prep_budget)]
     return _fit_backend(cands, feats)
 
 
@@ -490,6 +544,17 @@ def estimate_cost(
             # Slab splitting re-pads each slab to its own width; small
             # overhead on top of the whole-matrix estimate.
             bytes_ = int(bytes_ * 1.15)
+    elif cand.fmt == "sell_lr":
+        # Lane-row SELL, charged by its x accesses: on a v5e the SELL
+        # kernels' time follows x row loads, not bytes, and this tier does
+        # one per segment slot where SELL does one per stored slot.  So a
+        # segment slot is priced at one SELL slot's bytes, not at the 128
+        # lanes of values it streams (which would rank the tier 16x behind
+        # sell/pallas and prune it before any measurement).
+        from repro.kernels.ops import lanerow_counts
+
+        slots = sell_padded_slots(lanerow_counts(a), int(p["C"]), 1)
+        bytes_ = slots * (val_bytes + idx_bytes) + (m + n) * k * val_bytes
     elif cand.fmt == "bcsr":
         bm, bk = p["block"]
         n_blocks = bcsr_block_count(a, (int(bm), int(bk)))

@@ -48,6 +48,10 @@ class MatrixFeatures:
     # which sizes the SELL kernel's SMEM tiles.  Trailing default, like
     # x_density; not a FEATURE_NAMES axis.
     nnz_row_max: int = 0
+    # Most 128-column lane-rows of x that one row touches: lane-row SELL's
+    # width W_seg, which sizes that kernel's SMEM tiles and HBM value rows.
+    # Trailing default, like nnz_row_max; not a FEATURE_NAMES axis.
+    lanerow_max: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         """Plain-python dict, safe for JSON persistence inside a Plan.
@@ -124,7 +128,7 @@ def extract(
     finite: every downstream consumer ranks by these numbers, and one NaN
     here poisons the whole candidate ordering (see ``estimate_cost``).
     """
-    from repro.kernels.ops import VMEM_BUDGET_BYTES
+    from repro.kernels.ops import VMEM_BUDGET_BYTES, lanerow_counts
 
     m, n = a.shape
     lengths = np.diff(a.indptr).astype(np.float64)
@@ -145,4 +149,5 @@ def extract(
         x_fits_vmem=x_bytes <= VMEM_BUDGET_BYTES,
         x_density=x_density,
         nnz_row_max=int(lengths.max(initial=0)),
+        lanerow_max=int(lanerow_counts(a).max(initial=0)),
     )

@@ -46,6 +46,7 @@ from .candidates import (
     DEFAULT_PRUNE_FACTOR,
     REORDER_METHODS,
     enumerate_mesh_candidates,
+    fits_prep_budget,
     split_reorder,
 )
 from repro.runtime.executable import hoisted_jit
@@ -123,6 +124,10 @@ def prepare(
         return kops.sell_prepare(
             sell_from_csr(a, C=int(p["C"]), sigma=int(p["sigma"]), width_align=8),
             int(p.get("chunk_tile", 8)),
+        )
+    if cand.fmt == "sell_lr":
+        return kops.sell_lr_prepare(
+            a, C=int(p["C"]), chunk_tile=int(p["chunk_tile"])
         )
     if cand.fmt == "sell_blocked":
         if cand.impl == "pallas":
@@ -438,6 +443,11 @@ def runner(
             return lambda x: spmm_sell(dev, x, n_rows=m)
         return lambda x: spmv_sell(dev, x, n_rows=m)
 
+    if cand.fmt == "sell_lr":
+        if k > 1:
+            raise ValueError("sell_lr/pallas has no SpMM tier (k > 1)")
+        return lambda x: kops.sell_lr_spmv(prep, x)
+
     if cand.fmt == "sell_blocked":
         if cand.impl == "pallas":
             return lambda x: kops.sell_spmv_blocked_stacked(prep, x)
@@ -519,6 +529,14 @@ def sparse_rhs_runner(
 # ---------------------------------------------------------------------------
 # The facade
 # ---------------------------------------------------------------------------
+def _plan_fits(a: CSRMatrix, plan: Plan, k: int,
+               prep_budget: int | None) -> bool:
+    """Whether a cached plan's prepared operand fits ``prep_budget``."""
+    if prep_budget is None or plan.fmt != "sell_lr":
+        return True
+    return fits_prep_budget(plan.candidate, extract(a, k=k), prep_budget)
+
+
 class SparseOperator:
     """An autotuned sparse linear operator: ``y = op @ x``."""
 
@@ -577,6 +595,7 @@ class SparseOperator:
         race: bool = True,
         solver_step: bool = False,
         x_nnz: int | None = None,
+        prep_budget: int | None = None,
     ) -> "SparseOperator":
         """Autotune (or fetch the cached plan for) this matrix.
 
@@ -656,7 +675,7 @@ class SparseOperator:
         if not force_search:
             plan = cache.get(fp, kind, kk, backend=backend, scale=scale,
                              mesh_shape=mesh_shape or None)
-            if plan is not None:
+            if plan is not None and _plan_fits(a, plan, kk, prep_budget):
                 return cls(
                     a,
                     plan,
@@ -681,6 +700,7 @@ class SparseOperator:
             cands = enumerate_candidates(
                 feats, kind, k=kk,
                 reorders=REORDER_METHODS if include_reorder else (),
+                prep_budget=prep_budget,
             )
         costs = {
             c: estimate_cost(
@@ -811,6 +831,7 @@ class SparseOperator:
         cache: PlanCache | None = None,
         radius: float | None = None,
         exclude: Iterable[str] = (),
+        prep_budget: int | None = None,
     ) -> "SparseOperator":
         """A serve-NOW operator: no measured search, ever.
 
@@ -829,7 +850,8 @@ class SparseOperator:
         the cache; predicted plans are NEVER persisted — the fleet's
         background retune runs the real search and its measured plan both
         enters the cache and hot-swaps the serving executables.  ``exclude``
-        drops training fingerprints (leave-one-out evaluation).
+        drops training fingerprints (leave-one-out evaluation);
+        ``prep_budget`` is :meth:`build`'s, at each of the three steps.
         """
         from .predict import PREDICT_RADIUS, predict_candidate
 
@@ -840,7 +862,7 @@ class SparseOperator:
         scale = [int(a.shape[0]), int(a.shape[1]), int(a.nnz)]
         cache = default_cache() if cache is None else cache
         plan = cache.get(fp, kind, kk, backend=backend, scale=scale)
-        if plan is not None:
+        if plan is not None and _plan_fits(a, plan, kk, prep_budget):
             return cls(
                 a,
                 plan,
@@ -852,6 +874,7 @@ class SparseOperator:
             a, kind, kk, cache,
             feats=feats, backend=backend, exclude=set(exclude) | {fp},
             radius=PREDICT_RADIUS if radius is None else radius,
+            prep_budget=prep_budget,
         )
         cand = pred.candidate
         plan = Plan(
@@ -1061,6 +1084,17 @@ class SparseOperator:
 
     def matvec(self, x: jax.Array) -> jax.Array:
         return self @ x
+
+    @property
+    def x_loads_per_nnz(self) -> float | None:
+        """x row loads per nonzero of a SELL plan (stored slots / nnz) or a
+        lane-row SELL plan (segment slots / nnz); None for other tiers
+        (and for a reordered plan, whose prep nests its tier's)."""
+        key = {"sell": "cols", "sell_lr": "seg_row"}.get(self.plan.fmt)
+        slots = self._prep.get(key) if isinstance(self._prep, dict) else None
+        if slots is None:
+            return None
+        return slots.size / max(int(self.a.nnz), 1)
 
     def _csr_fallback(self) -> dict:
         if self._csr_dev is None:

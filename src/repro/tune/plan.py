@@ -56,7 +56,10 @@ __all__ = ["PLAN_VERSION", "Plan", "PlanCache", "fingerprint", "default_cache"]
 # x-density axis that its byte branch ranks on — the dense tiers now pay a
 # densify term under sparse-RHS kinds, so what an old plan would have
 # picked changes; pre-v6 plans are dropped at load and re-searched.
-PLAN_VERSION = 6
+# v7: lane-row SELL (sell_lr/pallas) joined the k = 1 space, with its own
+# branch of the byte model; a v6 plan never measured it, so it is dropped
+# at load and re-searched.
+PLAN_VERSION = 7
 
 _ENV_CACHE = "REPRO_TUNE_CACHE"
 _DEFAULT_CACHE = "~/.cache/repro_tune/plans.json"

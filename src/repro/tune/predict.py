@@ -37,7 +37,12 @@ import numpy as np
 
 from repro.core.formats import CSRMatrix
 
-from .candidates import Candidate, enumerate_candidates, estimate_cost
+from .candidates import (
+    Candidate,
+    enumerate_candidates,
+    estimate_cost,
+    fits_prep_budget,
+)
 from .features import MatrixFeatures, extract, feature_vector
 from .plan import PlanCache
 
@@ -67,13 +72,14 @@ class Prediction:
 
 
 def _byte_model_argmin(
-    a: CSRMatrix, feats: MatrixFeatures, kind: str, k: int
+    a: CSRMatrix, feats: MatrixFeatures, kind: str, k: int,
+    prep_budget: int | None = None,
 ) -> Candidate:
     """The fallback prior: cheapest byte-model estimate over the enumerated
     space — exactly the ranking the measured search prunes with, minus the
     measurement.  The scalar/interpret penalties already keep those tiers
     from ever being the argmin."""
-    cands = enumerate_candidates(feats, kind, k=k)
+    cands = enumerate_candidates(feats, kind, k=k, prep_budget=prep_budget)
     return min(cands, key=lambda c: estimate_cost(a, c, feats, k=k))
 
 
@@ -88,12 +94,15 @@ def predict_candidate(
     mesh_shape: Iterable[int] = (),
     exclude: Iterable[str] = (),
     radius: float = PREDICT_RADIUS,
+    prep_budget: int | None = None,
 ) -> Prediction:
     """Pick a serve-now candidate for ``a`` without a measured search.
 
     ``exclude`` drops training fingerprints (leave-one-out evaluation, or
-    the request's own fingerprint).  Always returns a candidate: the byte
-    model is the floor, never an exception.
+    the request's own fingerprint); a neighbor's candidate whose prepared
+    operand would not fit ``prep_budget`` bytes here is no training point.
+    Always returns a candidate: the byte model is the floor, never an
+    exception.
     """
     feats = extract(a, k=k) if feats is None else feats
     target = feature_vector(feats)
@@ -118,6 +127,8 @@ def predict_candidate(
                 cand = p.candidate
             except Exception:
                 continue  # params drifted: unusable as a training point
+            if not fits_prep_budget(cand, feats, prep_budget):
+                continue
             pool.append((p.fingerprint, cand, vec))
 
     if pool:
@@ -139,7 +150,7 @@ def predict_candidate(
                 n_neighbors=len(pool),
             )
     return Prediction(
-        candidate=_byte_model_argmin(a, feats, kind, k),
+        candidate=_byte_model_argmin(a, feats, kind, k, prep_budget),
         source="byte_model",
         distance=float("inf") if not pool else float(np.min(dists)),
         confident=False,

@@ -447,3 +447,31 @@ def test_bucket_program_takes_prepared_arrays_as_arguments():
     assert len(text) < a.nnz * 4 // 2
     ys = np.asarray(prog(*[zero] * 4))
     assert ys.shape == (a.shape[0], 4) and not ys.any()
+
+
+def test_summary_reports_x_loads_per_nnz_of_the_serving_plans():
+    """Each dense bucket's summary line carries its plan's x row loads per
+    nonzero: segment slots / nnz for lane-row SELL, stored slots / nnz for
+    SELL; a tier without slots (CSR here) is left out."""
+    from repro.tune import make
+
+    d, a = small(seed=71)
+    csr = SparseOperator.from_candidate(a, make("csr", "vector"), k=16)
+    rng = np.random.default_rng(72)
+    xs = [rng.standard_normal(a.shape[1]).astype(np.float32) for _ in range(3)]
+    loads = {}
+    for fmt, extra, slots in (("sell_lr", {}, "seg_row"),
+                              ("sell", {"sigma": 1}, "cols")):
+        op = SparseOperator.from_candidate(
+            a, make(fmt, "pallas", C=8, chunk_tile=16, **extra))
+        eng = SparseEngine(a, ks=(1, 16), ops={1: op, 16: csr})
+        ys = eng.run(xs[:1]) + eng.run(xs[1:])  # a k = 1 and a k = 16 batch
+        for x, y in zip(xs, ys):
+            np.testing.assert_allclose(np.asarray(y), d @ x, atol=2e-3)
+        summary = eng.stats.summary()
+        assert summary["by_bucket"] == {1: 1, 16: 1}
+        assert set(summary["x_loads_per_nnz"]) == {1}
+        loads[fmt] = summary["x_loads_per_nnz"][1]
+        assert loads[fmt] == pytest.approx(op._prep[slots].size / a.nnz,
+                                           abs=1e-4)
+    assert loads["sell_lr"] < loads["sell"]

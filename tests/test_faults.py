@@ -157,8 +157,10 @@ def test_plan_cache_concurrent_writer_fuzz(tmp_path):
 def test_injected_dispatch_failure_fails_futures_fifo_for_survivors():
     d, a = small(seed=3)
     plan = FaultPlan({"engine.dispatch": {"n": 3}})
-    eng = engine(a, ks=(4,), faults=plan,
-                 supervisor=Supervisor(max_retries=0, **SUP_KW))
+    # No repair probe during the storm: a probe fires the same site, and
+    # one that ran between two demotions would take one of the 3 fires.
+    sup = Supervisor(max_retries=0, **{**SUP_KW, "repair_interval_s": 60.0})
+    eng = engine(a, ks=(4,), faults=plan, supervisor=sup)
     xs = xs_for(a, 8)
     reqs = [eng.submit(x) for x in xs]
     eng.drain()

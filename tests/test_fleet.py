@@ -6,7 +6,14 @@ import jax.numpy as jnp
 from repro.core.formats import csr_from_dense
 from repro.runtime.engine import SparseEngine
 from repro.runtime.fleet import SparseFleet, _table_bytes
-from repro.tune import PlanCache, SparseOperator, make, prep_memo_stats
+from repro.tune import (
+    PlanCache,
+    SparseOperator,
+    enumerate_candidates,
+    extract,
+    make,
+    prep_memo_stats,
+)
 
 
 def small(seed=0, m=128, density=0.06):
@@ -227,6 +234,73 @@ def test_busy_tenants_are_never_evicted_over_budget_admission_counted():
     assert fl.stats_fleet.evictions == 0
     assert fl.stats_fleet.over_budget_admissions >= 1
     fl.drain()
+
+
+def _lane_row_plan(a, cache):
+    """Measure lane-row SELL alone for ``a``'s k = 1 bucket, so the cache
+    holds it as that bucket's plan; returns its value rows' bytes."""
+    lr = make("sell_lr", "pallas", C=8, chunk_tile=16)
+    op = SparseOperator.build(a, cache=cache, candidates=[lr], warmup=0,
+                              timed=1)
+    assert op.plan.candidate == lr
+    return op._prep["seg_vals"].nbytes
+
+
+def test_lane_row_tenant_admitted_beside_others_within_prep_budget():
+    """A tenant whose k = 1 plan is lane-row SELL is admitted from the
+    cache beside two others: its value rows (4 MiB at 128 rows, the chunk
+    count padded to 1,024 rows) fit a quarter of the budget, nobody is
+    evicted, and its bytes are charged to it."""
+    d1, a1 = small(seed=81)
+    _, a2 = small(seed=82)
+    _, a3 = small(seed=83)
+    cache = PlanCache()
+    seg_bytes = _lane_row_plan(a1, cache)
+    fl = fleet(cache=cache, budget_bytes=8 * seg_bytes)
+    assert fl.prep_budget == 2 * seg_bytes
+    t2 = fl.add_tenant("t2", a2)
+    t1 = fl.add_tenant("t1", a1)
+    t3 = fl.add_tenant("t3", a3)
+    assert t1.admitted_from[1] == "cache"
+    assert t1.engine.ops[1].plan.fmt == "sell_lr"
+    assert t1.nbytes >= seg_bytes
+    assert all(t.resident for t in (t1, t2, t3))
+    assert fl.stats_fleet.evictions == 0
+    assert fl.stats_fleet.over_budget_admissions == 0
+    x = xs_for(a1, 1)[0]
+    r = fl.submit("t1", x)
+    while r._ys is None:
+        if fl.step() == 0:
+            fl.flush()
+    np.testing.assert_allclose(np.asarray(r.y), d1 @ np.asarray(x), atol=2e-3)
+    fl.close()
+
+
+def test_lane_row_plan_over_prep_budget_is_neither_served_nor_searched():
+    """Where the value rows exceed a quarter of the budget, the cached
+    lane-row plan is passed over at admission (the tenant is predicted
+    onto another tier and nobody is evicted), and the retune's measured
+    search leaves the tier out."""
+    _, a1 = small(seed=84)
+    _, a2 = small(seed=85)
+    cache = PlanCache()
+    seg_bytes = _lane_row_plan(a1, cache)
+    fl = fleet(cache=cache, budget_bytes=4 * seg_bytes - 4)
+    assert fl.prep_budget < seg_bytes
+    t2 = fl.add_tenant("t2", a2)
+    t1 = fl.add_tenant("t1", a1)
+    assert t1.admitted_from[1] != "cache"
+    assert t1.engine.ops[1].plan.fmt != "sell_lr"
+    assert t1.nbytes < seg_bytes
+    assert t1.resident and t2.resident and fl.stats_fleet.evictions == 0
+    op = SparseOperator.build(a1, cache=cache, prep_budget=fl.prep_budget,
+                              warmup=0, timed=1)
+    assert not op.from_cache and op.plan.fmt != "sell_lr"
+    feats = extract(a1)
+    assert any(c.fmt == "sell_lr" for c in enumerate_candidates(feats))
+    assert not any(c.fmt == "sell_lr" for c in enumerate_candidates(
+        feats, prep_budget=fl.prep_budget))
+    fl.close()
 
 
 # -- scheduling -------------------------------------------------------------

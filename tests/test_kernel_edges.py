@@ -439,3 +439,82 @@ def test_spmspv_scatter_pallas_pipelined_matches_ref():
             )
         )
         np.testing.assert_allclose(y, ref, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Lane-row SELL (sell_lr_prepare / sell_lr_spmv) against float64 SciPy and
+# against the SELL kernel
+# ---------------------------------------------------------------------------
+def _csr_from_rows(n, rows):
+    """CSR with the given (column list) per row; values from the columns."""
+    from repro.core.formats import CSRMatrix
+
+    indptr = np.zeros(len(rows) + 1, np.int32)
+    indptr[1:] = np.cumsum([len(r) for r in rows])
+    indices = np.concatenate([np.asarray(r, np.int32) for r in rows]
+                             or [np.zeros(0, np.int32)])
+    data = (np.cos(indices.astype(np.float64) * 0.37) + 1.5).astype(np.float32)
+    return CSRMatrix((len(rows), n), indptr, indices, data)
+
+
+def _lane_row_case(name):
+    from repro.data.suite import generate
+
+    rng = np.random.default_rng(61)
+    if name == "banded_fem":  # ldoor's surrogate: runs of 6 within a band
+        return generate("ldoor", scale=0.002, seed=0)
+    if name == "run_crosses_lane_row":  # each run straddles 128k - 1 | 128k
+        return _csr_from_rows(512, [
+            list(range(128 * (1 + i % 3) - 3 - i % 4, 128 * (1 + i % 3) + 3))
+            for i in range(40)])
+    if name == "empty_rows":  # every third row empty, and a trailing run
+        rows = [sorted(rng.choice(300, 5, replace=False)) if i % 3 else []
+                for i in range(57)] + [[]] * 9
+        return _csr_from_rows(300, rows)
+    if name == "last_partial_lane_row":  # n = 300: lane-row 2 holds 44 cols
+        return _csr_from_rows(300, [[0, 256 + i // 2, 299] if i % 2 else [299 - i]
+                                    for i in range(44)])
+    if name == "row_at_max_segments":  # one row touches all 20 lane-rows
+        rows = [[5 * i] for i in range(70)]
+        rows[33] = list(range(3, 2560, 128))
+        return _csr_from_rows(2560, rows)
+    if name == "chunk_count_padding":  # 13 rows: the last chunk is part pad
+        return _csr_from_rows(200, [[i, 150 + i] for i in range(13)])
+    if name == "unsorted_columns":  # CSR columns not ascending in a row
+        rows = [list(rng.permutation(np.arange(i, 400, 37))) for i in range(30)]
+        return _csr_from_rows(400, rows)
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "banded_fem", "run_crosses_lane_row", "empty_rows",
+    "last_partial_lane_row", "row_at_max_segments", "chunk_count_padding",
+    "unsorted_columns",
+])
+def test_sell_lr_matches_scipy_float64_and_sell(case):
+    import scipy.sparse as sp
+
+    from repro.core.formats import sell_from_csr
+
+    a = _lane_row_case(case)
+    m, n = a.shape
+    x = np.random.default_rng(7).standard_normal(n).astype(np.float32)
+    # Copies: SciPy may sort a matrix's indices in place.
+    s = sp.csr_matrix((a.data.astype(np.float64), a.indices.copy(),
+                       a.indptr.copy()), shape=a.shape)
+    ref = s @ x.astype(np.float64)
+    scale = abs(s) @ np.abs(x.astype(np.float64)) + 1e-30
+
+    prep = kops.sell_lr_prepare(a, C=8, chunk_tile=16)
+    counts = kops.lanerow_counts(a)
+    assert prep["width"] == -(-max(int(counts.max(initial=0)), 1) // 8) * 8
+    assert prep["seg_vals"].shape == (prep["seg_row"].shape[0], 128)
+    y = np.asarray(kops.sell_lr_spmv(prep, jnp.asarray(x)))
+    assert y.shape == (m,)
+    assert np.all(np.abs(y - ref) <= 1e-6 * scale), case
+
+    sell = kops.sell_prepare(sell_from_csr(a, C=8, sigma=1, width_align=8), 8)
+    y_sell = np.asarray(kops.sell_spmv(sell, jnp.asarray(x)))
+    assert np.all(np.abs(y - y_sell) <= 1e-6 * scale), case
+    if case == "row_at_max_segments":
+        assert prep["width"] == 24 and counts[33] == 20

@@ -120,7 +120,7 @@ def test_slab_pipeline_dma_path_equals_direct_loads():
     """The double-buffered make_async_copy path must be numerically
     identical to the direct-load fallback (this interpreter models DMA
     semaphores, so the exact TPU-path slot/semaphore logic runs here) —
-    for all three kernels built on kernels/pipeline.slab_pipeline."""
+    for all four kernels built on kernels/pipeline.slab_pipeline."""
     from repro.kernels.sell_spmv import sell_spmv_blocked_pallas
     rng = np.random.default_rng(23)
 
@@ -147,6 +147,19 @@ def test_slab_pipeline_dma_path_equals_direct_loads():
         for p in (False, True)
     ]
     np.testing.assert_array_equal(outs[0], outs[1])
+
+    # Lane-row SELL: seg_row tiles to SMEM and value-row slabs to VMEM.
+    from repro.kernels.sell_spmv import sell_lr_spmv_pallas
+
+    lprep = kops.sell_lr_prepare(a, chunk_tile=16)
+    outs = [
+        np.asarray(sell_lr_spmv_pallas(
+            lprep["seg_row"], lprep["seg_vals"], x, width=lprep["width"],
+            interpret=True, pipelined=p))
+        for p in (False, True)
+    ]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_allclose(outs[1][:96], d @ np.asarray(x), atol=1e-4)
 
     # BCSR: block stream slabs (n_blocks not a multiple of block_tile,
     # so the zero-block padding rides the DMA path as well).

@@ -101,6 +101,40 @@ def test_sell_spmv_column_slab_compiles_at_mesh_2048(spec):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_sell_lr_spmv_compiles_at_ldoor(spec):
+    """Lane-row SELL at ldoor's surrogate: W_seg 8 (its rows touch at most
+    8 lane-rows of x), chunk_tile 16, so a tile is 1,024 segment slots and
+    a value slab 512 KiB; ``seg_vals`` is 3.9 GB of HBM.  The flat
+    ``seg_row`` needs no relayout copy (no temporaries)."""
+    from repro.kernels.sell_spmv import sell_lr_spmv_pallas
+
+    slots = _chunks(LDOOR_M) * 8 * 8
+    _, c = compile_for_chip(
+        lambda seg_row, seg_vals, x: sell_lr_spmv_pallas(
+            seg_row, seg_vals, x, width=8, chunk_tile=16, interpret=False
+        ),
+        spec((slots,), jnp.int32),
+        spec((slots, 128), jnp.float32),
+        spec((LDOOR_M,), jnp.float32),
+    )
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_sell_lr_value_rows_build_compiles_at_ldoor(spec):
+    """The device-side build of ldoor's 3.9 GB ``seg_vals`` from its
+    23.7M sorted (position, value) pairs: one scatter into zeros."""
+    from repro.kernels.ops import _lane_rows_from_coo
+
+    nnz, slots = 23_701_107, _chunks(LDOOR_M) * 8 * 8
+    _, c = compile_for_chip(
+        lambda flat, vals: _lane_rows_from_coo(flat, vals, n_slots=slots),
+        spec((nnz,), jnp.int32),
+        spec((nnz,), jnp.float32),
+    )
+    assert c.memory_analysis().output_size_in_bytes == slots * 128 * 4
+
+
 @pytest.mark.parametrize("k", [1, 16])
 def test_bcsr_spmm_compiles_at_ldoor(spec, k):
     """Lane-dense (8, 128) blocks, ldoor's band-sized x window."""

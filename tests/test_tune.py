@@ -458,12 +458,13 @@ def test_plan_version_6_drops_v5_entries_and_rebuilds(tmp_path):
     """Acceptance: the v6 bump (spmspv tier + x-density feature axis +
     densify term under sparse-RHS kinds) must drop v5-era entries at load —
     they were picked from a smaller space under the old model — and a fresh
-    build repopulates the file at the current version."""
+    build repopulates the file at the current version (7 since lane-row
+    SELL joined the space)."""
     import json
 
     from repro.tune import PLAN_VERSION
 
-    assert PLAN_VERSION == 6
+    assert PLAN_VERSION == 7
     _, a = small_csr(seed=23)
     fp = fingerprint(a)
     path = tmp_path / "plans.json"
@@ -480,7 +481,7 @@ def test_plan_version_6_drops_v5_entries_and_rebuilds(tmp_path):
     op = SparseOperator.build(a, cache=cache, warmup=0, timed=1)
     assert not op.from_cache  # stale plan re-searched, not served
     on_disk = json.loads(path.read_text())
-    assert all(e.get("version") == 6 for e in on_disk.values())
+    assert all(e.get("version") == PLAN_VERSION for e in on_disk.values())
     # Restarted process reloads the rebuilt table without searching.
     assert SparseOperator.build(a, cache=PlanCache(path)).from_cache
 
@@ -834,3 +835,149 @@ def test_tpu_cost_model_prices_lane_padding_of_narrow_xla_bcsr():
     assert tpu[merge] == cpu[merge]
     assert tpu[narrow] > 10 * tpu[merge] and tpu[narrow] > cpu[narrow]
     assert prune(tpu) == [merge]
+
+
+# ---------------------------------------------------------------------------
+# Lane-row SELL in the search space
+# ---------------------------------------------------------------------------
+SELL_LR_KEY = "sell_lr/pallas[C=8,chunk_tile=16]"
+
+
+def _on_tpu(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_sell_lr_enumerated_and_survives_prune_on_banded(monkeypatch):
+    """On a banded FEM surrogate (ldoor's generator) the tier is enumerated
+    at k = 1 for spmv and solver_step, and the TPU byte model, charging it
+    by x accesses, keeps it through prune: there it is the cheapest."""
+    from repro.tune.candidates import tpu_pallas_fits
+
+    a = generate("ldoor", scale=0.01, seed=0)
+    feats = extract(a)
+    assert 1 <= feats.lanerow_max <= 8
+    _on_tpu(monkeypatch)
+    for kind in ("spmv", "solver_step"):
+        cands = enumerate_candidates(feats, kind, k=1)
+        assert SELL_LR_KEY in {c.key() for c in cands}, kind
+    assert not any(c.fmt == "sell_lr" for c in
+                   enumerate_candidates(feats, "spmm", k=16))
+    assert not any(c.fmt == "sell_lr" for c in
+                   enumerate_candidates(feats, "spmv", include_pallas=False))
+    cands = enumerate_candidates(feats, "spmv")
+    lr = next(c for c in cands if c.key() == SELL_LR_KEY)
+    assert tpu_pallas_fits(lr, feats)
+    costs = {c: estimate_cost(a, c, feats, on_cpu=False) for c in cands}
+    assert lr in prune(costs)
+    assert costs[lr] == min(costs.values())
+    # Interpret mode on the CPU prices it out, like every Pallas tier.
+    cpu = {c: estimate_cost(a, c, feats, on_cpu=True) for c in cands}
+    assert lr not in prune(cpu)
+
+
+def test_sell_lr_excluded_by_smem_rule_on_powerlaw(monkeypatch):
+    """A power-law surrogate's longest rows touch hundreds of lane-rows, so
+    a tile of seg_row would not fit SMEM: the tier is not enumerated on
+    TPU, as sell/pallas is not."""
+    from repro.tune.candidates import SMEM_BUDGET_BYTES
+
+    a = generate("webbase-1M", scale=0.5, seed=0)
+    feats = extract(a)
+    assert 2 * 4 * 16 * 8 * feats.lanerow_max > SMEM_BUDGET_BYTES
+    _on_tpu(monkeypatch)
+    keys = {c.key() for c in enumerate_candidates(feats, "spmv")}
+    assert SELL_LR_KEY not in keys
+    assert not any(k.startswith("sell/pallas") for k in keys)
+
+
+def test_sell_lr_excluded_by_hbm_rule_above_4gib(monkeypatch):
+    """ldoor's shape: W_seg 8 needs 3.9 GB of value rows and is admitted;
+    W_seg 24 (the pattern of A + A^T, as CG's SPD shift has) needs 11.7 GB,
+    over HBM_BUDGET_BYTES, and is not, though its SMEM tile fits."""
+    import dataclasses
+
+    from repro.tune import make
+    from repro.tune.candidates import (
+        HBM_BUDGET_BYTES, SMEM_BUDGET_BYTES, tpu_pallas_fits,
+    )
+
+    _, a = small_csr(seed=62)
+    ldoor = dataclasses.replace(extract(a), m=952_203, n=952_203,
+                                x_bytes=952_203 * 4, lanerow_max=8)
+    lr = make("sell_lr", "pallas", C=8, chunk_tile=16)
+    assert tpu_pallas_fits(lr, ldoor)
+    assert 119_040 * 8 * 8 * 512 <= HBM_BUDGET_BYTES
+    wide = dataclasses.replace(ldoor, lanerow_max=20)
+    assert 2 * 4 * 16 * 8 * 24 <= SMEM_BUDGET_BYTES
+    assert 119_040 * 8 * 24 * 512 > HBM_BUDGET_BYTES
+    assert not tpu_pallas_fits(lr, wide)
+    _on_tpu(monkeypatch)
+    assert SELL_LR_KEY in {c.key() for c in enumerate_candidates(ldoor)}
+    assert SELL_LR_KEY not in {c.key() for c in enumerate_candidates(wide)}
+
+
+# Sums of every estimate (TPU and CPU model, spmv at k = 1 and spmm at
+# k = 16) of the tiers that were there before lane-row SELL, from the
+# previous release's byte model: adding the tier changed none of them.
+PRE_SELL_LR_ESTIMATE_SUMS = {
+    ("cant", "spmv", False): (218297577.51698193, 19),
+    ("cant", "spmv", True): (10000134473.516983, 19),
+    ("cant", "spmm", False): (60105101.12626328, 12),
+    ("cant", "spmm", True): (3345366157.1262636, 12),
+    ("scircuit", "spmv", False): (304968675.65158826, 19),
+    ("scircuit", "spmv", True): (10993250219.651588, 19),
+    ("scircuit", "spmm", False): (146199248.44067705, 12),
+    ("scircuit", "spmm", True): (4202971192.4406767, 12),
+    ("webbase-1M", "spmv", False): (1030340347.5168276, 19),
+    ("webbase-1M", "spmv", True): (20895212051.516827, 19),
+    ("webbase-1M", "spmm", False): (864600409.6593407, 12),
+    ("webbase-1M", "spmm", True): (12683728433.65934, 12),
+}
+
+
+@pytest.mark.parametrize("name", ["cant", "scircuit", "webbase-1M"])
+def test_other_tiers_cost_estimates_unchanged_by_sell_lr(name):
+    a = generate(name, scale=1 / 256, seed=0)
+    feats = extract(a)
+    for kind, k in (("spmv", 1), ("spmm", 16)):
+        others = [c for c in enumerate_candidates(feats, kind, k=k)
+                  if c.fmt != "sell_lr"]
+        for on_cpu in (False, True):
+            total = sum(estimate_cost(a, c, feats, k=k, on_cpu=on_cpu)
+                        for c in others)
+            want, count = PRE_SELL_LR_ESTIMATE_SUMS[(name, kind, on_cpu)]
+            assert len(others) == count
+            assert total == pytest.approx(want, rel=1e-12), (kind, on_cpu)
+
+
+def test_plan_version_7_drops_v6_entries_and_rebuilds(tmp_path):
+    """The v7 bump (lane-row SELL in the k = 1 space): a v6 plan never
+    measured the tier, so it is dropped at load and the search runs again."""
+    import json
+
+    from repro.tune import PLAN_VERSION
+
+    assert PLAN_VERSION == 7
+    _, a = small_csr(seed=63)
+    fp = fingerprint(a)
+    path = tmp_path / "plans.json"
+    v6_entry = {
+        "fingerprint": fp, "kind": "spmv", "fmt": "sell", "impl": "pallas",
+        "params": {"C": 8, "sigma": 1, "chunk_tile": 16}, "est_cost": 1.0,
+        "measured_s": 1e-4, "n_candidates": 19, "n_measured": 9, "k": 1,
+        "backend": "cpu", "scale": [a.shape[0], a.shape[1], a.nnz],
+        "mesh_shape": [], "n_raced": 0, "features": None,
+        "predicted_from": "", "failed": {}, "version": 6,
+    }
+    path.write_text(json.dumps({f"{fp}:spmv:k1": v6_entry}))
+    cache = PlanCache(path)
+    assert len(cache) == 0 and cache.get(fp, "spmv", 1, backend="cpu") is None
+    op = SparseOperator.build(a, cache=cache, warmup=0, timed=1)
+    assert not op.from_cache
+    assert op.plan.features["lanerow_max"] == int(
+        __import__("repro.kernels.ops", fromlist=["lanerow_counts"])
+        .lanerow_counts(a).max())
+    on_disk = json.loads(path.read_text())
+    assert [e["version"] for e in on_disk.values()] == [7]
