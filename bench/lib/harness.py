@@ -1,8 +1,13 @@
 """Run one cell once: set up, measure for ``seconds``, check, reduce.
 
-The result is the benchmark's last line (see ``bench/run.py``).  What the
-run did goes to ``log`` on the way: the device, where the pattern and each
-plan came from, requests offered, served and late, and every compared
+The result is the benchmark's last line (see ``bench/run.py``).  The cell's
+kind, ``bench/kinds/<request>.py`` as its traffic mix names it, builds the
+system, drives the window, reduces what came back and checks it
+(``bench/kinds/__init__.py``); the harness does what every kind shares:
+the device, the profiler, the compile counter, the memory peak, the
+reference's clock, the per-layer readers and the last line.  What the run
+did goes to ``log`` on the way: the device, where the pattern and each
+plan came from, what was offered, served and late, and every compared
 number beside its limit.
 """
 from __future__ import annotations
@@ -14,11 +19,13 @@ import math
 import shutil
 import time
 from pathlib import Path
+from typing import Callable
 
 import numpy as np
 
-from . import reference, registry, traffic
-from .suite import PatternStore, rng_for, spd_shift
+from . import reference, registry
+from .spans import LONG_STEP_S, SpanSummary, compile_delta, reduce_spans
+from .suite import PatternStore, rng_for
 from .trace import TraceSummary, load_events, reduce_events, save_events
 
 
@@ -28,7 +35,8 @@ class NoAccelerator(RuntimeError):
 
 @dataclasses.dataclass
 class RunRecord:
-    """What a per-layer reader may read: the run's counters and its trace."""
+    """What a per-layer reader may read: the run's counters, its trace, and
+    the program's spans in it (``bench.lib.spans.reduce_spans``)."""
 
     n_rows: int
     n_cols: int
@@ -36,6 +44,7 @@ class RunRecord:
     device_kind: str
     counters: dict
     trace: TraceSummary | None
+    spans: SpanSummary | None = None
 
 
 def device_info(chips: int, require_tpu: bool):
@@ -62,23 +71,25 @@ def device_vectors(seed: int, stream: int, count: int, n: int) -> list:
     return list(make(key))
 
 
-def _p95(values: np.ndarray) -> float:
+def p95(values: np.ndarray) -> float:
     """Nearest-rank 95th percentile."""
     v = np.sort(values)
     return float(v[max(0, math.ceil(0.95 * v.size) - 1)])
 
 
-def _host(arrays) -> np.ndarray:
+def host_stack(arrays) -> np.ndarray:
     return np.stack([np.asarray(x) for x in arrays])
 
 
-def _csr(a):
+def program_csr(a):
+    """The benchmark's host matrix as the program's ``CSRMatrix``."""
     from repro.core.formats import CSRMatrix
 
     return CSRMatrix(a.shape, a.indptr, a.indices, a.data)
 
 
-def _check(value, limit) -> dict:
+def compared(value, limit) -> dict:
+    """One entry of ``checks``: a number beside its limit."""
     return {"value": None if value is None else float(value), "limit": float(limit)}
 
 
@@ -92,6 +103,71 @@ def check_stated(cfg: dict, a) -> None:
                          f"generator builds {built}")
 
 
+def compile_counts():
+    """The program's compile counter now, or None for a program without one."""
+    try:
+        from repro.runtime.executable import compile_counts as counts
+    except ImportError:
+        return None
+    return counts()
+
+
+@dataclasses.dataclass
+class Setup:
+    """What a kind's ``build`` gets: the cell's files and where it runs."""
+
+    config: dict
+    mix: dict
+    seed: int
+    devs: list
+    scale: float
+    cache_dir: Path
+    bench_dir: Path
+    log: Callable
+
+    def matrix(self):
+        """``(store, a)``: the configuration's pattern, cached in
+        ``cache_dir``, with float32 values from the seed; at the stated
+        scale, refused unless it is the matrix the configuration states."""
+        cfg = self.config
+        store = PatternStore(cfg["name"], cfg["generator"], self.scale,
+                             cfg["structure_seed"], self.cache_dir / "patterns",
+                             self.bench_dir)
+        a = store.matrix(self.seed)
+        if self.scale == float(cfg["scale"]):
+            check_stated(cfg, a)
+        return store, a
+
+    def log_matrix(self, store, a, t: float, note: str = "") -> None:
+        self.log(f"matrix {self.config['name']}@{self.scale:g}: {a.shape[0]}x"
+                 f"{a.shape[1]}, nnz={a.nnz}{note}; pattern from cache: "
+                 f"{store.hits}; {time.perf_counter() - t:.2f} s")
+
+    def plan_cache(self):
+        """The program's plan cache for this configuration, in the checkout."""
+        from repro.tune import PlanCache
+
+        return PlanCache(self.cache_dir / "plans" / f"{self.config['name']}.json")
+
+    def log_plans(self, ops: dict, t: float) -> None:
+        for k, op in sorted(ops.items()):
+            self.log(f"plan {op.plan.kind} k={k}: {op.plan.candidate.key()} from "
+                     f"{'the plan cache' if op.from_cache else 'a measured search'} "
+                     f"(measured {op.plan.measured_s * 1e3:.4f} ms)")
+        self.log(f"plans ready in {time.perf_counter() - t:.2f} s")
+
+
+@dataclasses.dataclass
+class Reduced:
+    """What a kind's ``reduce`` makes of a window's outcome."""
+
+    e2e: dict  # end-to-end values under the benchmark's names, setup_s aside
+    counters: dict  # for the per-layer readers (RunRecord.counters)
+    attempted: int
+    failed: int
+    sample: object  # what the kind's ``check`` compares, on the host
+
+
 @dataclasses.dataclass
 class Cell:
     """A cell set up and warmed: what the measured window drives."""
@@ -100,12 +176,12 @@ class Cell:
     workload: dict
     config: dict
     mix: dict
-    kind: str
-    a: object  # the host matrix (bench.lib.suite.Csr)
+    kind: object  # the module bench/kinds/<request>.py
+    a: object  # the host matrix (bench.lib.suite.Csr) RunRecord counts
     devs: list
     device: dict
-    system: object  # SparseEngine or SparseSolver
-    ops: dict
+    system: object
+    ops: dict  # the prepared operators, by block width
     pool: list  # device-resident request vectors or right-hand sides
     e2e_entries: list
     layer_entries: list
@@ -126,64 +202,20 @@ def build_cell(
     log=print,
 ) -> Cell:
     """Load, build and warm up one cell; ``setup_s`` ends here."""
-    import jax
-
     t_start = time.perf_counter() if t_start is None else t_start
     bench_dir = Path(bench_dir)
     bm = registry.load_benchmark(bench_dir.parent)
     w = registry.find_workload(bm, name)
     cfg = registry.load_config(w["config"], bench_dir)
     mix = registry.load_traffic(w["traffic"], bench_dir)
+    kind = registry.load_kind(mix["request"], bench_dir)
     e2e_entries, layer_entries = registry.cell_metrics(bm, name)
     devs, device = device_info(int(w["chips"]), require_tpu)
     log(f"device: {json.dumps(device)}")
     cache_dir = Path(cache_dir) if cache_dir is not None else bench_dir / ".cache"
     scale = float(cfg["scale"] if scale is None else scale)
-    kind = "cg" if mix["request"] == "cg" else "serve"
-
-    t = time.perf_counter()
-    store = PatternStore(cfg["name"], cfg["generator"], scale,
-                         cfg["structure_seed"], cache_dir / "patterns")
-    a = store.matrix(seed)
-    if scale == float(cfg["scale"]):
-        check_stated(cfg, a)
-    if kind == "cg":
-        a = spd_shift(a, store.spd_pattern(), margin=cfg["cg"]["spd_margin"])
-    n = a.shape[0]
-    log(f"matrix {cfg['name']}@{scale:g}: {a.shape[0]}x{a.shape[1]}, "
-        f"nnz={a.nnz}{' (spd_shift)' if kind == 'cg' else ''}; pattern from "
-        f"cache: {store.hits}; {time.perf_counter() - t:.2f} s")
-
-    from repro.runtime.engine import SparseEngine
-    from repro.runtime.solver import SparseSolver
-    from repro.tune import PlanCache
-
-    plans = PlanCache(cache_dir / "plans" / f"{cfg['name']}.json")
-    t = time.perf_counter()
-    if kind == "serve":
-        ks = tuple(int(k) for k in cfg["engine"]["ks"])
-        system = SparseEngine(_csr(a), ks=ks, cache=plans)
-        ops = system.ops
-        pool = device_vectors(seed, 1, max(int(mix["x_pool"]), max(ks)), n)
-    else:
-        system = SparseSolver(_csr(a), cache=plans)
-        ops = {1: system.op(1)}
-        pool = device_vectors(seed, 2, int(mix["b_pool"]), n)
-    for k, op in sorted(ops.items()):
-        log(f"plan {op.plan.kind} k={k}: {op.plan.candidate.key()} from "
-            f"{'the plan cache' if op.from_cache else 'a measured search'} "
-            f"(measured {op.plan.measured_s * 1e3:.4f} ms)")
-    log(f"plans ready in {time.perf_counter() - t:.2f} s")
-
-    t = time.perf_counter()
-    if kind == "serve":  # every bucket's program, and its result slices
-        for k in ks:
-            jax.block_until_ready(system.run(pool[:k]))
-        system.stats = type(system.stats)()
-    else:
-        system.cg(pool[0], tol=float(cfg["cg"]["tol"]),
-                  maxiter=int(cfg["cg"]["maxiter"]))
-    log(f"warm-up {time.perf_counter() - t:.2f} s")
+    system, ops, pool, a = kind.build(
+        Setup(cfg, mix, seed, devs, scale, cache_dir, bench_dir, log))
     setup_s = time.perf_counter() - t_start
     log(f"setup_s {setup_s:.4f}")
     return Cell(name, w, cfg, mix, kind, a, devs, device, system, ops, pool,
@@ -199,149 +231,97 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
                         dump_trace=dump_trace)
 
 
-def measure_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
-                 log=print, control: bool = False,
-                 dump_trace: Path | None = None) -> tuple[dict, dict]:
-    """Returns ``(result, extra)``: the last line's object, and readings
-    that only ``bench/calibrate.py`` prints (the control's, when asked)."""
+def window(cell: Cell, seconds: float, seed: int, trace_dir: Path | None = None):
+    """Drive one window of the cell's traffic, under the profiler when
+    ``trace_dir`` is given.  Returns the kind's outcome and what the
+    program's compile counter counted meanwhile (None without one)."""
     import jax
 
-    from repro.tune.operator import evict_prepared
-
-    name, kind, mix, cfg, a = cell.name, cell.kind, cell.mix, cell.config, cell.a
-    system, ops, pool, devs = cell.system, cell.ops, cell.pool, cell.devs
-    device = dict(cell.device)
-    n = a.shape[0]
-    tol, maxiter = float(cfg["cg"]["tol"]), int(cfg["cg"]["maxiter"])
-    cell.system = cell.ops = cell.pool = None  # this run frees them
-    trace_dir = cell.cache_dir / "trace" / name
-    if trace:
+    if trace_dir is not None:
         shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    before = compile_counts()
     try:
-        if kind == "serve" and mix["loop"] == "open":
-            out = traffic.serve_open(system, pool, mix, seconds, seed)
-        elif kind == "serve":
-            out = traffic.serve_closed(system, pool, mix, seconds, seed)
-        else:
-            out = traffic.cg_closed(system, pool, mix, seconds, seed, tol, maxiter)
+        out = cell.kind.drive(cell, seconds, seed)
     finally:
-        if trace:
+        after = compile_counts()
+        if trace_dir is not None:
             jax.profiler.stop_trace()
-    stats = (devs[0].memory_stats() or {})
-    device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    return out, compile_delta(before, after)
 
-    counters: dict = {}
-    e2e: dict = {"setup_s": cell.setup_s}
-    extra: dict = {}
-    if kind == "serve":
-        st = system.stats
-        counters["engine"] = {"padded_cols": st.padded_cols,
-                              "occupied_cols": st.occupied_cols,
-                              "dispatched": dict(st.dispatched)}
-        summary = st.summary()
-        log(f"engine stats: {json.dumps({k: summary[k] for k in ('dispatches', 'by_bucket', 'served_cols', 'padded_cols', 'retries', 'demotions', 'failed_batches')})}")
-        late = out.lateness_s
-        log(f"requests: offered {out.offered}, served {out.served}, failed or "
-            f"never came {out.failed}; window {out.window_s:.4f} s; most pending "
-            f"{out.max_pending}; generator late by mean "
-            f"{(late.mean() if late.size else 0) * 1e3:.4f} ms, p95 "
-            f"{(np.percentile(late, 95) if late.size else 0) * 1e3:.4f} ms, max "
-            f"{(late.max() if late.size else 0) * 1e3:.4f} ms")
-        if out.served:
-            e2e["spmv_p95_ms"] = _p95(out.latencies_s) * 1e3
-            e2e["spmv_rps"] = out.served / out.window_s
-            log(f"latency ms: p50 {np.median(out.latencies_s) * 1e3:.4f}, p95 "
-                f"{e2e['spmv_p95_ms']:.4f}, max {out.latencies_s.max() * 1e3:.4f}")
-        idx = sorted(out.kept)
-        ys = _host(out.kept[j] for j in idx) if idx else np.zeros((0, n), np.float32)
-        xs = _host(pool)[[j % len(pool) for j in idx]] if idx else ys
-        buckets = [out.kept_bucket[j] for j in idx]
-        attempted, failed = out.offered, out.failed
-        faults = system.supervisor.faults()
-        system.close()
-    else:
-        its = out.iterations
-        counters["cg"] = {"iterations": list(its)}
-        log(f"solves: {out.solves} in {out.window_s:.4f} s, failed {out.failed}, "
-            f"iterations {sorted(set(its))}, all converged {all(out.converged)}")
-        if out.solves:
-            e2e["cg_solve_s"] = out.window_s / out.solves
-        idx = sorted(out.xs)
-        xs = _host(out.xs[i] for i in idx) if idx else np.zeros((0, n), np.float32)
-        bs = _host(pool)[[i % len(pool) for i in idx]] if idx else xs
-        attempted = out.solves + out.failed
-        failed = out.failed + sum(not c for c in out.converged)
-        faults = system.supervisor.faults()
-    if faults:
-        log(f"supervisor faults: {faults}")
-    for op in ops.values():
+
+def read_layers(cell: Cell, counters: dict, events: list,
+                long_s: float = LONG_STEP_S) -> tuple[RunRecord, dict]:
+    """The record the per-layer readers read, from one load of the trace's
+    events, and what each of the cell's readers read from it (None where
+    it found nothing)."""
+    a = cell.a
+    record = RunRecord(a.shape[0], a.shape[1], a.nnz, cell.device["kind"],
+                       counters, reduce_events(events),
+                       reduce_spans(events, long_s=long_s))
+    return record, {m["name"]: registry.load_reader(m["name"], cell.bench_dir)(record)
+                    for m in cell.layer_entries}
+
+
+def measure_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
+                 log=print, control: bool = False,
+                 dump_trace: Path | None = None) -> tuple[dict, dict]:
+    """Returns ``(result, extra)``: the last line's object, and the control's
+    readings, which only ``bench/calibrate.py`` prints (when asked)."""
+    from repro.tune.operator import evict_prepared
+
+    kind = cell.kind
+    device = dict(cell.device)
+    trace_dir = cell.cache_dir / "trace" / cell.name if trace else None
+    out, compiles = window(cell, seconds, seed, trace_dir)
+    stats = (cell.devs[0].memory_stats() or {})
+    device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    red = kind.reduce(cell, out, log)
+    kind.close(cell)
+    for op in cell.ops.values():
         evict_prepared(op.plan.fingerprint)
-    del system, ops, pool, out
+    cell.system = cell.ops = cell.pool = None  # this run frees them
+    del out
     gc.collect()
 
     t = time.perf_counter()
-    a64 = reference.scipy_f64(a)
-    if kind == "serve":
-        errs = reference.spmv_errors(a64, xs, ys)
-        for b in sorted(set(buckets), key=str):
-            sel = [i for i, bb in enumerate(buckets) if bb == b]
-            log(f"bucket {b}: {len(sel)} checked, worst spmv_err "
-                f"{errs[sel].max():.4e}")
-        checks = {
-            "spmv_err": _check(errs.max() if errs.size else None,
-                               reference.SPMV_ERR_LIMIT),
-            "unanswered": _check(failed, 0),
-        }
-        if control:
-            extra["control_spmv_err"] = float(
-                reference.spmv_errors(a64, xs, reference.control_spmv(a, xs)).max())
-        extra["spmv_err"] = errs.tolist()
-    else:
-        res = reference.cg_residuals(a64, bs, xs)
-        checks = {
-            "cg_residual": _check(res.max() if res.size else None,
-                                  reference.CG_RESIDUAL_LIMIT),
-            "unconverged": _check(failed, 0),
-        }
-        if control:
-            ctrl = reference.control_cg(a, bs[:3], tol, maxiter)
-            extra["control_cg_residual"] = reference.cg_residuals(a64, bs[:3], ctrl).tolist()
-        extra["cg_residual"] = res.tolist()
+    a64 = reference.scipy_f64(cell.a)
+    checks = kind.check(cell, a64, red, log)
+    extra = kind.control(cell, a64, red) if control else {}
     log(f"reference check {time.perf_counter() - t:.2f} s")
     correct = all(c["value"] is not None and c["value"] <= c["limit"]
                   for c in checks.values())
 
-    result: dict = {"correct": correct, "attempted": int(attempted),
-                    "failed": int(failed)}
+    result: dict = {"correct": correct, "attempted": int(red.attempted),
+                    "failed": int(red.failed)}
     if not trace:
-        metrics = {}
-        for m in cell.e2e_entries:
-            if m["name"] in e2e:
-                metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
-        result["metrics"] = metrics
+        e2e = {"setup_s": cell.setup_s, **red.e2e}
+        result["metrics"] = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
+                             for m in cell.e2e_entries if m["name"] in e2e}
         result["device"] = device
     else:
-        summary = reduce_events(load_events(trace_dir))
+        t = time.perf_counter()
+        events = load_events(trace_dir)
         if dump_trace is not None:
-            save_events(load_events(trace_dir), dump_trace)
+            save_events(events, dump_trace)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        record, readings = read_layers(cell, {**red.counters, "runtime": compiles},
+                                       events)
+        log(f"trace read: {len(events)} events in {time.perf_counter() - t:.2f} s")
+        del events
+        summary = record.trace
         log(f"trace: window {summary.window_s:.4f} s, busy {summary.busy_s:.4f} s, "
             f"{summary.n_devices} device(s)")
         for mod, (cnt, sec) in sorted(summary.modules.items(), key=lambda kv: -kv[1][1])[:12]:
             log(f"trace program {mod}: {cnt} launches, {sec:.6f} s")
         log(f"trace idle by host span: {json.dumps(summary.gap_totals)}")
-        record = RunRecord(a.shape[0], a.shape[1], a.nnz, device["kind"],
-                           counters, summary)
-        metrics = {}
-        for m in cell.layer_entries:
-            value = registry.load_reader(m["name"], cell.bench_dir)(record)
-            if value is not None:
-                metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-        result["metrics"] = metrics
+        result["metrics"] = {m["name"]: {"value": float(readings[m["name"]]),
+                                         "unit": m["unit"]}
+                             for m in cell.layer_entries
+                             if readings[m["name"]] is not None}
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         result["device"] = device

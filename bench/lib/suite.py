@@ -24,11 +24,14 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import inspect
 import json
 import os
 from pathlib import Path
 
 import numpy as np
+
+from . import registry
 
 # Bumped whenever the code below could make a different pattern, so a
 # pattern cached by an older copy is never read back.
@@ -143,10 +146,20 @@ FAMILIES = {
 }
 
 
-def make_pattern(table1: dict, scale: float, structure_seed: int):
+def family(name: str, bench_dir: Path | None = None):
+    """The pattern family ``name``: one of ``FAMILIES``, else the
+    ``pattern`` function of ``<bench_dir>/families/<name>.py``."""
+    if name in FAMILIES:
+        return FAMILIES[name]
+    return registry.load_module("families", name,
+                                bench_dir or registry.BENCH_DIR).pattern
+
+
+def make_pattern(table1: dict, scale: float, structure_seed: int,
+                 bench_dir: Path | None = None):
     """``(n, indptr, indices)`` of the Table-1 matrix, duplicates merged."""
     rng = np.random.default_rng(int(structure_seed) * 1000 + int(table1["idx"]))
-    n, rows, cols = FAMILIES[table1["family"]](table1, scale, rng)
+    n, rows, cols = family(table1["family"], bench_dir)(table1, scale, rng)
     keys = np.unique(rows.astype(np.int64) * n + cols.astype(np.int64))
     indptr = np.zeros(n + 1, np.int64)
     np.cumsum(np.bincount(keys // n, minlength=n), out=indptr[1:])
@@ -182,17 +195,24 @@ class PatternStore:
 
     The cache file is named from the configuration and a digest of what
     made it (the Table-1 row, the scale, the structure seed and
-    ``PATTERN_VERSION``), so an edited configuration never reads a stale
-    pattern.  ``cache_dir=None`` keeps everything in memory.
+    ``PATTERN_VERSION``; for a family of ``bench/families``, its file too),
+    so an edited configuration never reads a stale pattern.
+    ``cache_dir=None`` keeps everything in memory.
     """
 
     def __init__(self, name: str, table1: dict, scale: float,
-                 structure_seed: int, cache_dir: Path | None):
+                 structure_seed: int, cache_dir: Path | None,
+                 bench_dir: Path | None = None):
         self.table1 = dict(table1)
         self.scale = float(scale)
         self.structure_seed = int(structure_seed)
-        what = json.dumps([self.table1, self.scale, self.structure_seed,
-                           PATTERN_VERSION], sort_keys=True)
+        self.bench_dir = bench_dir
+        key = [self.table1, self.scale, self.structure_seed, PATTERN_VERSION]
+        fam = family(self.table1["family"], bench_dir)  # fails at once if none
+        if self.table1["family"] not in FAMILIES:
+            key.append(hashlib.sha256(
+                Path(inspect.getfile(fam)).read_bytes()).hexdigest())
+        what = json.dumps(key, sort_keys=True)
         digest = hashlib.sha256(what.encode()).hexdigest()[:12]
         self._base = None if cache_dir is None else Path(cache_dir) / f"{name}-{digest}"
         self.hits: dict[str, bool] = {}
@@ -220,7 +240,7 @@ class PatternStore:
         if self._a is None:
             def make():
                 n, indptr, indices = make_pattern(
-                    self.table1, self.scale, self.structure_seed)
+                    self.table1, self.scale, self.structure_seed, self.bench_dir)
                 return {"n": np.asarray(n), "indptr": indptr, "indices": indices}
             self._a = self._load_or_make("a", make)
         return self._a

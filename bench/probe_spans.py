@@ -7,12 +7,12 @@
 Sets a cell up once (as ``bench/run.py`` does) and drives its traffic for
 one window per entry of ``--windows``: ``0`` untraced, ``1`` under the
 profiler, every window with the same seed.  Each window prints one line,
-``window {...}``, with its end-to-end numbers (``spmv_p95_ms`` and
-``spmv_rps``, or ``cg_solve_s``) and what the program's compile counter
-(``repro.runtime.executable.compile_counts``) counted in it
-(``runtime.compiles``: backend compiles).  A traced window adds the
-device's idle share, the programs of the "XLA Modules" line, the cell's
-accepted per-layer metrics as ``bench/run.py --trace 1`` reads them, the
+``window {...}``, with the end-to-end numbers and the attempted and
+failed counts its kind's ``reduce`` gives (``bench/kinds``), and what the
+program's compile counter (``repro.runtime.executable.compile_counts``)
+counted in it (``runtime.compiles``: backend compiles).  A traced window
+adds the device's idle share, the programs of the "XLA Modules" line, the
+cell's accepted per-layer metrics as ``bench/run.py --trace 1`` reads them, the
 spans of ``bench.lib.spans`` reduced (count, total, self time and longest
 per name; idle time by innermost span) and the per-layer readings they
 give: ``engine.host_ms``, ``engine.step_max_ms``,
@@ -42,31 +42,6 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-def _run_window(cell, seconds: float, seed: int):
-    from bench.lib import traffic
-
-    mix, cfg = cell.mix, cell.config
-    if cell.kind == "serve" and mix["loop"] == "open":
-        return traffic.serve_open(cell.system, cell.pool, mix, seconds, seed)
-    if cell.kind == "serve":
-        return traffic.serve_closed(cell.system, cell.pool, mix, seconds, seed)
-    return traffic.cg_closed(cell.system, cell.pool, mix, seconds, seed,
-                             float(cfg["cg"]["tol"]), int(cfg["cg"]["maxiter"]))
-
-
-def _end_to_end(cell, out) -> dict:
-    from bench.lib.harness import _p95
-
-    if cell.kind == "cg":
-        return {"solves": out.solves, "failed": out.failed,
-                "cg_solve_s": out.window_s / out.solves if out.solves else None}
-    rec = {"offered": out.offered, "served": out.served, "failed": out.failed}
-    if out.served:
-        rec["spmv_p95_ms"] = _p95(out.latencies_s) * 1e3
-        rec["spmv_rps"] = out.served / out.window_s
-    return rec
-
-
 def _host_events_in(events, t0: float, t1: float, top: int = 8) -> dict:
     """Seconds of each host event name (spans included) inside [t0, t1],
     largest first."""
@@ -82,28 +57,13 @@ def _host_events_in(events, t0: float, t1: float, top: int = 8) -> dict:
     return dict(sorted(total.items(), key=lambda kv: -kv[1])[:top])
 
 
-def _counters(cell, out) -> dict:
-    """What the harness hands its per-layer readers, for this window."""
-    if cell.kind == "cg":
-        return {"cg": {"iterations": list(out.iterations)}}
-    st = cell.system.stats
-    return {"engine": {"padded_cols": st.padded_cols,
-                       "occupied_cols": st.occupied_cols,
-                       "dispatched": dict(st.dispatched)}}
-
-
 def _traced_readings(cell, counters: dict, events, long_s: float) -> dict:
-    from bench.lib import registry, spans
-    from bench.lib.harness import RunRecord
-    from bench.lib.trace import WINDOW_SPAN, reduce_events
+    from bench.lib import spans
+    from bench.lib.harness import read_layers
+    from bench.lib.trace import WINDOW_SPAN
 
-    summary = reduce_events(events)
-    a = cell.a
-    record = RunRecord(a.shape[0], a.shape[1], a.nnz, cell.device["kind"],
-                       counters, summary)
-    accepted = {m["name"]: registry.load_reader(m["name"], cell.bench_dir)(record)
-                for m in cell.layer_entries}
-    sp = spans.reduce_spans(events, long_s=long_s)
+    record, accepted = read_layers(cell, counters, events, long_s)
+    summary, sp = record.trace, record.spans
     window = next(e for e in events if e.name == WINDOW_SPAN)
     w0 = window.start_ns
     for step in sp.long_steps:
@@ -138,43 +98,23 @@ def probe(cell, windows: list, seconds: float, seed: int, *,
           long_s: float = 0.3, until_long_step: bool = False, log=log) -> list:
     """Drive ``cell`` for one window per entry of ``windows`` (True: under
     the profiler); returns the windows' records, each also logged."""
-    import jax
-
-    from bench.lib.spans import compile_delta, runtime_compiles
+    from bench.lib.harness import window
+    from bench.lib.spans import runtime_compiles
     from bench.lib.trace import load_events
-
-    try:
-        from repro.runtime.executable import compile_counts
-    except ImportError:  # a program without the counter
-        def compile_counts():
-            return None
 
     trace_dir = cell.cache_dir / "trace" / f"probe-{cell.name}"
     records = []
     for i, traced in enumerate(windows):
-        if traced:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 1
-            jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-        if cell.kind == "serve":
-            cell.system.stats = type(cell.system.stats)()
-        before = compile_counts()
         t = time.perf_counter()
-        try:
-            out = _run_window(cell, seconds, seed)
-        finally:
-            after = compile_counts()
-            if traced:
-                jax.profiler.stop_trace()
-        compiles = compile_delta(before, after)
+        out, compiles = window(cell, seconds, seed, trace_dir if traced else None)
         rec = {"workload": cell.name, "window": i, "traced": traced,
-               "seed": seed, "seconds": time.perf_counter() - t,
-               **_end_to_end(cell, out), "compiles": compiles,
-               "runtime.compiles": runtime_compiles({"runtime": compiles})}
-        counters = _counters(cell, out)
+               "seed": seed, "seconds": time.perf_counter() - t}
+        red = cell.kind.reduce(cell, out, log)
         del out
+        counters = {**red.counters, "runtime": compiles}
+        rec.update(red.e2e, attempted=red.attempted, failed=red.failed,
+                   compiles=compiles)
+        rec["runtime.compiles"] = runtime_compiles(counters)
         if traced:
             t = time.perf_counter()
             rec.update(_traced_readings(cell, counters, load_events(trace_dir),
@@ -217,8 +157,7 @@ def main(argv=None) -> int:
         return 3
     probe(cell, [w == "1" for w in args.windows.split(",")], args.seconds,
           args.seed, long_s=args.long, until_long_step=args.until_long_step)
-    if cell.kind == "serve":
-        cell.system.close()
+    cell.kind.close(cell)
     return 0
 
 

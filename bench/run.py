@@ -4,7 +4,8 @@
     python bench/run.py --workload ldoor.serve_open --seed 7 --seconds 40 --trace 0
 
 The cell (``workloads`` in ``BENCHMARK.json``) names a configuration under
-``bench/configs`` and a traffic mix under ``bench/traffic``.  The run makes
+``bench/configs`` and a traffic mix under ``bench/traffic``, whose
+``request`` names the cell's kind under ``bench/kinds``.  The run makes
 the matrix's values and the requests from ``--seed``, loads or searches the
 plans and warms every program up (``setup_s``), measures for ``--seconds``,
 then checks what the measured window returned against a float64 reference.

@@ -67,6 +67,10 @@ def test_benchmark_json_keeps_to_the_contract():
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
         cells.add(w["name"])
     assert config_names == {w["config"] for w in bm["workloads"]}
+    for mix in (root / "bench" / "traffic").glob("*.json"):  # each names a kind
+        kind = registry.load_kind(json.loads(mix.read_text())["request"])
+        for hook in ("build", "drive", "reduce", "close", "check"):
+            assert callable(getattr(kind, hook)), (mix.name, hook)
     e2e_names = set()
     for m in bm["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
